@@ -89,6 +89,8 @@ def main() -> None:
         return
 
     from benchmarks import common
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.quick:
         common.set_scale("quick")
 

@@ -14,12 +14,19 @@ in one grid:
     x (S, N, d_in) or (N, d_in) shared  ×  u (S, d_in, r), v (S, d_out, r)
       → (S, N, d_out) f32,   y_s = (x_s @ u_s) @ v_sᵀ
 
-Grid is (S, N-blocks): each step keeps one member's full (d_in, r) and
-(d_out, r) factor panels VMEM-resident (r ≤ 64 in practice, so the panels
-are KiB-scale) and streams a (block_n, d_in) activation tile through two
-small GEMMs — no cross-step accumulation, every output tile is written
-exactly once. The ragged N tail zero-pads to the block grid and is sliced
-off, like every kernel in this package.
+Grid is (S, N-blocks, d_out-blocks), d_out innermost. At the first
+d_out block of each (member, N-block) the kernel computes the rank-r
+projection t = x @ u from a (block_n, d_in) activation tile and the
+member's (d_in, r) panel into a VMEM scratch; every d_out block then
+writes one (block_n, block_o) output tile t @ v_blockᵀ. So x @ u runs
+once per (member, N-block), not once per output tile, and the x and u
+blocks are fetched once per (member, N-block) because their block index
+does not move along the d_out axis. Tiling d_out is what lets the
+tied-unembed site (d_out = vocabulary, 128256 for llama3.2-1b) compile:
+with the whole d_out in one block its output window alone exceeds VMEM.
+`block_n` shrinks with d_in so the double-buffered x tile stays inside a
+fixed VMEM budget. The ragged N tail zero-pads to the block grid and is
+sliced off, like every kernel in this package.
 
 Shared-x form: when `x` has no member axis (the first layer of a factored
 forward, before activations diverge per member), the x BlockSpec maps every
@@ -32,32 +39,56 @@ the off-TPU production path.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels.compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 F32 = jnp.float32
 
-BLOCK_N = 256            # activation rows per tile; (256, d) f32 ≤ 2 MiB VMEM
+BLOCK_N = 256            # activation rows per tile, before the d_in cap
+BLOCK_O = 2048           # output columns per tile: (256, 2048) f32 = 2 MiB
+X_TILE_BYTES = 2 << 20   # VMEM budget of one (block_n, d_in) x buffer
+LANE = 128
 
 
-def _bgmv_kernel(x_ref, u_ref, v_ref, out_ref):
-    """One (member, N-block) step: y = (x @ u) @ vᵀ, f32 accumulation.
+def _out_block(d_out: int) -> int:
+    """d_out tile: the whole axis when it fits in BLOCK_O, else the largest
+    lane-aligned divisor of d_out up to BLOCK_O (no padding), else BLOCK_O
+    with the axis zero-padded to a multiple of it."""
+    if d_out <= BLOCK_O:
+        return d_out
+    for b in range(BLOCK_O, LANE - 1, -LANE):
+        if d_out % b == 0:
+            return b
+    return BLOCK_O
+
+
+def _row_block(n: int, d_in: int, itemsize: int, block_n: int) -> int:
+    """N tile: at most `block_n`, capped so one x buffer stays within
+    X_TILE_BYTES, a multiple of 16 rows (the bf16 sublane tile) or all of
+    a smaller N."""
+    cap = max(16, X_TILE_BYTES // (d_in * itemsize) // 16 * 16)
+    b = min(block_n, cap)
+    return b if n > b else max(n, 1)
+
+
+def _bgmv_kernel(x_ref, ut_ref, vt_ref, out_ref, t_ref):
+    """One (member, N-block, d_out-block) step, f32 accumulation.
 
     x_ref is (block_n, d_in) for shared x or (1, block_n, d_in) for
-    per-member x — the reshape normalizes both layouts."""
-    x = x_ref[...].reshape(-1, x_ref.shape[-1]).astype(F32)   # (bn, d_in)
-    u = u_ref[0].astype(F32)                                  # (d_in, r)
-    v = v_ref[0].astype(F32)                                  # (d_out, r)
-    t = jax.lax.dot_general(x, u, (((1,), (0,)), ((), ())),
-                            preferred_element_type=F32)       # (bn, r)
-    y = jax.lax.dot_general(t, v, (((1,), (1,)), ((), ())),
-                            preferred_element_type=F32)       # (bn, d_out)
-    out_ref[0] = y
+    per-member x — the reshape normalizes both layouts. The factor panels
+    arrive transposed, (1, r, d_in) and (1, r, block_o)."""
+    @pl.when(pl.program_id(2) == 0)
+    def _project():
+        x = x_ref[...].reshape(-1, x_ref.shape[-1]).astype(F32)
+        t_ref[...] = jax.lax.dot_general(
+            x, ut_ref[0].astype(F32), (((1,), (1,)), ((), ())),
+            preferred_element_type=F32)                      # (bn, r)
+
+    out_ref[0] = jax.lax.dot_general(
+        t_ref[...], vt_ref[0].astype(F32), (((1,), (0,)), ((), ())),
+        preferred_element_type=F32)                          # (bn, bo)
 
 
 def bgmv_pallas(x, u, v, *, block_n: int = BLOCK_N, interpret: bool = False):
@@ -71,29 +102,39 @@ def bgmv_pallas(x, u, v, *, block_n: int = BLOCK_N, interpret: bool = False):
     assert x.shape == ((n, d_in) if shared else (s, n, d_in)), \
         (x.shape, u.shape)
     assert v.shape == (s, d_out, r), (v.shape, u.shape)
-    block_n = min(block_n, max(n, 1))
+    block_n = _row_block(n, d_in, x.dtype.itemsize, block_n)
     pad = (-n) % block_n
     if pad:                       # ragged tail: zero rows, sliced off below
         width = ((0, pad), (0, 0)) if shared else ((0, 0), (0, pad), (0, 0))
         x = jnp.pad(x, width)
-    n_blocks = (n + pad) // block_n
+    block_o = _out_block(d_out)
+    pad_o = (-d_out) % block_o
+    if pad_o:                     # zero output columns, sliced off below
+        v = jnp.pad(v, ((0, 0), (0, pad_o), (0, 0)))
+    # r-minor factor panels would fill r of 128 lanes, and the compiler
+    # relayouts such an operand into a lane-padded copy 128/r times its
+    # size (328 MB for the tied unembed at S=5, r=8); transposed, d is the
+    # lane axis and the copy is the factor's own size.
+    ut, vt = jnp.swapaxes(u, 1, 2), jnp.swapaxes(v, 1, 2)
 
     if shared:
-        x_spec = pl.BlockSpec((block_n, d_in), lambda i, j: (j, 0))
+        x_spec = pl.BlockSpec((block_n, d_in), lambda i, j, k: (j, 0))
     else:
-        x_spec = pl.BlockSpec((1, block_n, d_in), lambda i, j: (i, j, 0))
+        x_spec = pl.BlockSpec((1, block_n, d_in), lambda i, j, k: (i, j, 0))
     out = pl.pallas_call(
         _bgmv_kernel,
-        grid=(s, n_blocks),
+        grid=(s, (n + pad) // block_n, (d_out + pad_o) // block_o),
         in_specs=[
             x_spec,
-            pl.BlockSpec((1, d_in, r), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, d_out, r), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, r, d_in), lambda i, j, k: (i, 0, 0)),
+            pl.BlockSpec((1, r, block_o), lambda i, j, k: (i, 0, k)),
         ],
-        out_specs=pl.BlockSpec((1, block_n, d_out), lambda i, j: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((s, n + pad, d_out), F32),
-        compiler_params=CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
+        out_specs=pl.BlockSpec((1, block_n, block_o),
+                               lambda i, j, k: (i, j, k)),
+        out_shape=jax.ShapeDtypeStruct((s, n + pad, d_out + pad_o), F32),
+        scratch_shapes=[pltpu.VMEM((block_n, r), F32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(x, u, v)
-    return out[:, :n] if pad else out
+    )(x, ut, vt)
+    return out[:, :n, :d_out] if pad or pad_o else out

@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _chunk_kernel(q_ref, k_ref, v_ref, lc_ref, s_ref, y_ref, s_out_ref, *,
                   scalar_decay: bool, pre: bool, bonus_ref=None):
@@ -121,7 +119,7 @@ def gla_chunk_pallas(q, k, v, lc, state, *, pre=False, bonus=None,
             jax.ShapeDtypeStruct((b, h, l, vd), v.dtype),
             jax.ShapeDtypeStruct((b, h, kd, vd), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(*args)

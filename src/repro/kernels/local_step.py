@@ -49,8 +49,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels.compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 F32 = jnp.float32
 
@@ -130,7 +129,7 @@ def matmul_blocked(a: jax.Array, b: jax.Array, *, block_m: int = BLOCK_M,
                   pl.BlockSpec((block_k, block_n), lambda i, j, k: (k, j))],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m + pm, n + pn), F32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(ap, bp)
@@ -230,7 +229,7 @@ def sgd_update_flat(p_flat: jax.Array, g_flat: jax.Array, *, lr: float,
         in_specs=[pl.BlockSpec((1, block_p), lambda i: (0, i))] * 2,
         out_specs=pl.BlockSpec((1, block_p), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, p + pad), F32),
-        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(pp[None], gp[None])
     return out[0, :p].astype(p_flat.dtype)

@@ -1,16 +1,15 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container / the dry-run host) kernels run in interpret mode —
-the kernel body executes as jax ops, bit-identical math, no Mosaic. On TPU
-(`jax.default_backend() == "tpu"`) the same call sites compile the real
-kernels. `repro.models.*` uses the pure-jnp formulations by default and can
-be switched to these via config (use_pallas) — both paths share oracles.
+The routing is a function of the backend alone. On TPU
+(`jax.default_backend() == "tpu"`) every call site compiles the real
+Mosaic kernels. Elsewhere the local-step ops and `bgmv` take their pure-jnp
+twins (the production path off-TPU), and the remaining wrappers run their
+kernels in interpret mode, the kernel body executing as jax ops. Oracles
+for both routes live in `kernels/ref.py`.
 """
 from __future__ import annotations
 
 import functools
-import os
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -22,38 +21,21 @@ from repro.kernels.local_step import conv2d_gemm, maxpool2x2, sgd_update_tree
 from repro.kernels.pool_distance import (distances_from_stats, factor_gram,
                                          pool_distance_stats)
 
-# Backend probes, resolved lazily ONCE per process (the backend cannot
-# change after jax initializes; re-probing `jax.default_backend()` on every
-# kernel call was pure per-call overhead). `REPRO_KERNEL_INTERPRET=1`
-# forces interpret mode on TPU — the kernel bodies execute as jax ops for
-# parity debugging against the ref paths; `=0` forces it off.
-_INTERPRET: Optional[bool] = None
-_ON_TPU: Optional[bool] = None
+
+@functools.cache
+def _use_pallas() -> bool:
+    """Real Mosaic kernels on TPU, the pure-jnp twins elsewhere —
+    interpret-mode Pallas inside a training loop is strictly slower than
+    XLA's fused jnp lowering, so off-TPU the jnp twin IS the production
+    path. Resolved once per process: the backend cannot change after jax
+    initializes."""
+    return jax.default_backend() == "tpu"
 
 
 def _interpret() -> bool:
-    global _INTERPRET
-    if _INTERPRET is None:
-        env = os.environ.get("REPRO_KERNEL_INTERPRET", "").strip().lower()
-        if env in ("1", "true", "yes", "on"):
-            _INTERPRET = True
-        elif env in ("0", "false", "no", "off"):
-            _INTERPRET = False
-        else:
-            _INTERPRET = jax.default_backend() != "tpu"
-    return _INTERPRET
-
-
-def _use_pallas() -> bool:
-    """Routing for the local-step ops: real Mosaic kernels on TPU, the
-    pure-jnp twins elsewhere — interpret-mode Pallas inside a training
-    loop is strictly slower than XLA's fused jnp lowering, so off-TPU the
-    jnp twin IS the production path (ROADMAP item 2). With
-    REPRO_KERNEL_INTERPRET=1 on TPU the kernels still run, interpreted."""
-    global _ON_TPU
-    if _ON_TPU is None:
-        _ON_TPU = jax.default_backend() == "tpu"
-    return _ON_TPU
+    """Interpret mode for the kernels that always run as Pallas: off-TPU
+    only."""
+    return not _use_pallas()
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "bq", "bk"))
@@ -147,7 +129,7 @@ def bgmv(x, u, v):
     elsewhere (interpret-mode Pallas in a scoring loop is strictly slower
     than XLA's fused lowering, same routing as the local-step ops)."""
     if _use_pallas():
-        return bgmv_pallas(x, u, v, interpret=_interpret())
+        return bgmv_pallas(x, u, v)
     from repro.kernels.ref import bgmv_ref
     return bgmv_ref(x, u, v)
 
@@ -158,8 +140,7 @@ def fused_conv2d(x, w, b):
     cliff, DESIGN.md §9) and vmaps over per-run weights as a batched
     matmul (no grouped-conv fallback, DESIGN.md §6). Pallas kernel on TPU,
     jnp GEMM twin elsewhere."""
-    return conv2d_gemm(x, w, b, use_pallas=_use_pallas(),
-                       interpret=_interpret())
+    return conv2d_gemm(x, w, b, use_pallas=_use_pallas())
 
 
 def fused_maxpool2x2(x):
@@ -175,5 +156,4 @@ def fused_sgd(params, grads, *, lr, wd=0.0):
     directly — the math is elementwise, so both routes are bit-identical
     to `optimizers.sgd`'s update rule."""
     return sgd_update_tree(params, grads, lr=lr, wd=wd,
-                           use_pallas=_use_pallas(),
-                           interpret=_interpret())
+                           use_pallas=_use_pallas())

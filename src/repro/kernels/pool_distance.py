@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 BLOCK_P = 65536          # 256 KiB f32 per member-row tile
 
 
@@ -92,7 +90,7 @@ def _pool_distance_stats_batched(w_flat, pool_flat, *, block_p=BLOCK_P,
         ],
         out_specs=[pl.BlockSpec((1, c, 1), lambda i, j: (i, 0, 0))] * 4,
         out_shape=[jax.ShapeDtypeStruct((b, c, 1), jnp.float32)] * 4,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(w_flat, pool_flat)
@@ -148,7 +146,7 @@ def factor_gram(a, *, block_p=BLOCK_P_GRAM, interpret=False):
         in_specs=[pl.BlockSpec((1, m, block_p), lambda i, j: (i, 0, j))],
         out_specs=pl.BlockSpec((1, m, m), lambda i, j: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, m, m), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(a)

@@ -33,15 +33,6 @@ OUT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                        "experiments", "dryrun")
 
 
-def _cost_analysis(compiled) -> dict:
-    """`Compiled.cost_analysis()` returns `list[dict]` (one entry per
-    program) on some jax versions and a flat dict on others — normalize."""
-    c = compiled.cost_analysis()
-    if isinstance(c, list):
-        return c[0] if c else {}
-    return c
-
-
 def _sharding_tree(spec_tree, mesh):
     return jax.tree.map(lambda s: NamedSharding(mesh, s), spec_tree,
                         is_leaf=lambda x: isinstance(x, P))
@@ -126,12 +117,12 @@ def dryrun_one(arch: str, shape_name: str, mesh_kind: str,
         compiled = _compile("")                  # rolled — deployment graph
         t_compile = time.time() - t0
         mem = compiled.memory_analysis()
-        cost_a = _cost_analysis(compiled)
+        cost_a = compiled.cost_analysis()
         coll_a = roofline.collective_bytes(compiled.as_text())
         t1 = time.time()
         compiled_b = _compile("2")
         t_compile_b = time.time() - t1
-        cost_b = _cost_analysis(compiled_b)
+        cost_b = compiled_b.cost_analysis()
         coll_b = roofline.collective_bytes(compiled_b.as_text())
         for k in ("REPRO_SCAN_UNROLL", "REPRO_INNER_UNROLL", "REPRO_ATTN_BLOCK",
                   "REPRO_GLA_CHUNK", *(extra_env or {})):

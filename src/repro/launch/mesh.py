@@ -11,18 +11,27 @@ Target hardware: TPU v5e pod slices.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes):
+    """`jax.make_mesh` with Auto axes: the engine places arrays with
+    NamedShardings and lets the compiler propagate them, so sharding must
+    not enter the array types (Explicit axes, jax's default, make eager
+    indexing of a sharded run axis an error)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_local_mesh():
     """Whatever devices exist locally, as a (data, model) mesh (model=1)."""
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return _mesh((n, 1), ("data", "model"))
 
 
 def make_batch_mesh(n_runs: int = 0):
@@ -34,7 +43,7 @@ def make_batch_mesh(n_runs: int = 0):
     if n_runs:
         while n > 1 and n_runs % n:
             n -= 1
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return _mesh((n, 1), ("data", "model"))
 
 
 def make_cohort_mesh(flat: int = 0):
@@ -48,7 +57,7 @@ def make_cohort_mesh(flat: int = 0):
     if flat:
         while n > 1 and flat % n:
             n -= 1
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return _mesh((n, 1), ("data", "model"))
 
 
 # TPU v5e roofline constants (per chip) — used by repro.analysis.roofline

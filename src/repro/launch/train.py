@@ -26,6 +26,7 @@ from repro.configs import FedConfig, get_arch
 from repro.data import (DataPlan, dirichlet_partition, make_domain_datasets,
                         make_image_dataset, make_lm_dataset)
 from repro.data.partition import domain_shift_partition
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 
 
@@ -64,6 +65,13 @@ def build_clients(args, cfg):
     iters = [DataPlan(c, args.batch, seed=args.seed * 100 + i)
              for i, c in enumerate(clients)]
     return iters, test_batch
+
+
+def arch_config(name: str, reduced: bool):
+    """The named architecture at its published widths, or its smoke-scale
+    variant when `reduced` (the paper CNN has none)."""
+    cfg = get_arch(name)
+    return cfg.reduced() if reduced and cfg.family != "cnn" else cfg
 
 
 def make_eval(model, cfg, test_batch):
@@ -111,9 +119,8 @@ def main():
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
 
-    cfg = get_arch(args.arch)
-    if args.reduced or cfg.family != "cnn":
-        cfg = cfg.reduced() if args.arch != "paper-cnn" else cfg
+    enable_compile_cache()
+    cfg = arch_config(args.arch, args.reduced)
     model = build_model(cfg)
     iters, test_batch = build_clients(args, cfg)
     eval_fn = make_eval(model, cfg, test_batch)

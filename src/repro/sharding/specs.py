@@ -15,7 +15,6 @@ from __future__ import annotations
 from typing import Any, Callable, Optional, Sequence, Tuple
 
 import jax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -59,8 +58,8 @@ def shard_map_flat(fn: Callable, mesh: Mesh,
     path (pinned in tests/test_fleet.py on a 1-device mesh)."""
     spec = flat_axis_spec(mesh)
     in_specs = tuple(spec if lead else P() for lead in leading)
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=spec,
-                     check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=spec,
+                         check_vma=False)
 
 
 def _axsize(mesh, axes):

@@ -46,6 +46,22 @@ def test_arch_constructs_and_reduces(name):
     assert red.n_layers <= cfg.n_layers and red.d_model <= cfg.d_model
 
 
+@pytest.mark.parametrize("name,reduced", [("llama3.2-1b", False),
+                                          ("llama3.2-1b", True),
+                                          ("paper-cnn", True)])
+def test_train_cli_reduces_only_when_asked(name, reduced):
+    """`launch/train.py --arch X` trains X at its published widths;
+    `--reduced` selects the smoke-scale variant (the CNN has none)."""
+    from repro.launch.train import arch_config
+    cfg = arch_config(name, reduced)
+    want = get_arch(name)
+    if reduced and want.family != "cnn":
+        want = want.reduced()
+    assert cfg == want
+    if name == "llama3.2-1b" and not reduced:
+        assert (cfg.d_model, cfg.d_ff, cfg.vocab_size) == (2048, 8192, 128256)
+
+
 def test_get_arch_unknown_lists_choices():
     with pytest.raises(KeyError, match="paper-cnn"):
         get_arch("llama99-typo")

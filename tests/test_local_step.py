@@ -1,6 +1,6 @@
 """Fused local-step kernel validation (`repro.kernels.local_step`).
 
-Four contracts:
+Three contracts:
 
 1. *Oracle agreement* — `matmul_blocked` (interpret mode, the same kernel
    body the TPU target compiles) matches `ref.matmul_ref` across ragged
@@ -17,8 +17,6 @@ Four contracts:
    phases scan-compiled (DataPlans) with params bit-identical to the
    per-step iterator path, sequential and batched — the contract that let
    the `DataPlan(scan=False)` conv carve-out be deleted.
-4. *Probe caching* — `ops._interpret()` resolves once per process and the
-   `REPRO_KERNEL_INTERPRET` env override forces either branch.
 """
 import dataclasses
 
@@ -304,27 +302,3 @@ def test_cnn_scanned_bit_identical_batched():
     for s, b in zip(seq, batch):
         _assert_trees_bitwise_equal(s.params, b.params)
 
-
-# ---------------------------------------------------------------------------
-# 4. Probe caching + env override
-# ---------------------------------------------------------------------------
-
-def test_interpret_probe_caches_and_env_overrides(monkeypatch):
-    """`ops._interpret()` probes `jax.default_backend()` once per process;
-    REPRO_KERNEL_INTERPRET forces either branch at first resolution (the
-    TPU parity-debugging hook); later env changes don't flip the cache."""
-    from repro.kernels import ops
-    saved = ops._INTERPRET
-    try:
-        ops._INTERPRET = None
-        monkeypatch.setenv("REPRO_KERNEL_INTERPRET", "1")
-        assert ops._interpret() is True
-        monkeypatch.setenv("REPRO_KERNEL_INTERPRET", "0")
-        assert ops._interpret() is True          # cached, not re-probed
-        ops._INTERPRET = None
-        assert ops._interpret() is False         # fresh probe honors env
-        ops._INTERPRET = None
-        monkeypatch.delenv("REPRO_KERNEL_INTERPRET")
-        assert ops._interpret() is (jax.default_backend() != "tpu")
-    finally:
-        ops._INTERPRET = saved
