@@ -20,6 +20,7 @@ from typing import Any, Callable, Dict, Optional, Sequence
 
 import jax
 
+from repro import obs
 from repro.api.results import RunResult
 from repro.api.strategies import get_strategy_spec
 from repro.configs.base import FedConfig
@@ -127,8 +128,9 @@ def _run(experiment: Optional[Experiment] = None, **kwargs) -> RunResult:
     t0 = time.time()
     # For plan strategies `fn` is the sequential interpreter backend bound
     # to the registered plan (register_plan); opaque callables run as-is.
-    out = spec.fn(experiment)
-    return finalize_result(experiment, out, time.time() - t0)
+    with obs.span(obs.LAUNCH):
+        out = spec.fn(experiment)
+        return finalize_result(experiment, out, time.time() - t0)
 
 
 def run(experiment: Optional[Experiment] = None, **kwargs) -> RunResult:

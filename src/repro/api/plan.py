@@ -44,6 +44,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.api.results import ClientRecord, RoundRecord, StrategyOutput
 from repro.api.trainer import LocalTrainer, stack_trees, unstack_tree
 from repro.data.plan import (all_want_scan, stack_plan_arrays, wants_scan)
@@ -219,7 +220,16 @@ def _make_trainer(loss_fn: Callable, fed, plan: StrategyPlan) -> LocalTrainer:
 
 
 def _eval(exp, params) -> Optional[float]:
-    return float(exp.eval_fn(params)) if exp.eval_fn is not None else None
+    if exp.eval_fn is None:
+        return None
+    with obs.span(obs.EVAL):
+        return float(exp.eval_fn(params))
+
+
+def _client_end(exp, rec, params) -> None:
+    if exp.callbacks.on_client_end is not None:
+        with obs.span(obs.CALLBACK):
+            exp.callbacks.on_client_end(rec, params)
 
 
 def _eval_slice(e, stacked: PyTree, i: int) -> Optional[float]:
@@ -313,8 +323,9 @@ def _interpret_sequenced(exp, plan: StrategyPlan,
     cycles = plan.topology.resolved_cycles(exp)
     m = _resolved_init(exp, plan)
     if _wants_warmup(exp, plan):
-        m = _train_visit(trainer, m, exp.client_iters[schedule[0]],
-                         fed.e_warmup)
+        with obs.span(obs.WARMUP):
+            m = _train_visit(trainer, m, exp.client_iters[schedule[0]],
+                             fed.e_warmup)
 
     clients: List[ClientRecord] = []
     rounds: List[RoundRecord] = []
@@ -325,26 +336,25 @@ def _interpret_sequenced(exp, plan: StrategyPlan,
                    if block.kind == "custom" else None)
         for r in range(cycles):
             for rank, ci in enumerate(schedule):
-                if block.kind == "pool":
-                    m, pool, models = _run_block(trainer, block, m,
-                                                 exp.client_iters[ci],
-                                                 None, exp)
-                else:
-                    m, _, models = _run_block(trainer, block, m,
-                                              exp.client_iters[ci],
-                                              step_fn, exp)
-                if plan.records == "clients":
-                    rec = ClientRecord(client=int(ci), rank=rank,
-                                       models=models,
-                                       global_metric=_eval(exp, m))
-                    clients.append(rec)
-                    if exp.callbacks.on_client_end is not None:
-                        exp.callbacks.on_client_end(rec, m)
+                with obs.span(obs.VISIT, rank=rank, client=int(ci)):
+                    if block.kind == "pool":
+                        m, pool, models = _run_block(trainer, block, m,
+                                                     exp.client_iters[ci],
+                                                     None, exp)
+                    else:
+                        m, _, models = _run_block(trainer, block, m,
+                                                  exp.client_iters[ci],
+                                                  step_fn, exp)
+                    if plan.records == "clients":
+                        rec = ClientRecord(client=int(ci), rank=rank,
+                                           models=models,
+                                           global_metric=_eval(exp, m))
+                        clients.append(rec)
+                        _client_end(exp, rec, m)
             if plan.records == "rounds":
                 rec = RoundRecord(round=r, global_metric=_eval(exp, m))
                 rounds.append(rec)
-                if exp.callbacks.on_client_end is not None:
-                    exp.callbacks.on_client_end(rec, m)
+                _client_end(exp, rec, m)
     return StrategyOutput(params=m, clients=clients, rounds=rounds,
                           final_pool=pool if plan.keep_final_pool else None)
 
@@ -380,8 +390,7 @@ def _interpret_independent(exp, plan: StrategyPlan,
         if plan.records == "clients_noeval":
             rec = ClientRecord(client=int(ci), rank=int(ci), models=models)
             clients.append(rec)
-            if exp.callbacks.on_client_end is not None:
-                exp.callbacks.on_client_end(rec, m)
+            _client_end(exp, rec, m)
     params = tree_mean(outs) if plan.aggregate == "tree_mean" else outs[-1]
     # Like the sequenced interpreter, "final pool" means the last visited
     # client's pool — the one whose diversity state is freshest.

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.api.pools import PoolBackend, backend_for
 from repro.api.results import ModelRecord
 from repro.configs.base import FedConfig
@@ -44,17 +45,18 @@ def hp_regularized_loss(loss_fn: Callable, fed: FedConfig,
     def full_loss(params, batch, pool, alpha, beta):
         task = loss_fn(params, batch)
         total = task
-        if fed.use_d1:
-            d1 = backend.d1(params, pool, fed.distance_measure)
-            if fed.log_scale_distances:
-                d1 = D.log_scale(d1, task)
-            total = total - alpha * d1
-        if fed.use_d2:
-            d2 = D.d2_anchor_distance(params, pool.first(),
-                                      fed.distance_measure)
-            if fed.log_scale_distances:
-                d2 = D.log_scale(d2, task)
-            total = total + beta * d2
+        with jax.named_scope(obs.REG):
+            if fed.use_d1:
+                d1 = backend.d1(params, pool, fed.distance_measure)
+                if fed.log_scale_distances:
+                    d1 = D.log_scale(d1, task)
+                total = total - alpha * d1
+            if fed.use_d2:
+                d2 = D.d2_anchor_distance(params, pool.first(),
+                                          fed.distance_measure)
+                if fed.log_scale_distances:
+                    d2 = D.log_scale(d2, task)
+                total = total + beta * d2
         return total, task
 
     return full_loss
@@ -214,7 +216,9 @@ def _scan_steps(task_and_grads: Callable, opt: Optimizer, params: PyTree,
     def body(carry, si):
         p, o = carry
         s, row = si
-        task, grads = task_and_grads(p, _gather(arrays, row))
+        with jax.named_scope(obs.TASK):
+            batch = _gather(arrays, row)
+        task, grads = task_and_grads(p, batch)
         p, o = opt.update(p, grads, o, s)
         return (p, o), task
 
@@ -327,7 +331,8 @@ def _compiled_steps(loss_fn: Callable, fed: FedConfig, opt_name: str,
         # their current step bodies. EVERY variant — per-step, scanned,
         # batched, shard-mapped — is built over the SAME resolved loss, so
         # the cross-path bit-identity contracts hold by construction.
-        step_loss = fused_loss_for(loss_fn)
+        # Its ops, and their backward, are named step.task (repro.obs).
+        step_loss = jax.named_scope(obs.TASK)(fused_loss_for(loss_fn))
         plain_core = _scanned_train_core(step_loss, opt)
         local_core = _scanned_local_core(step_loss, fed, opt, backend)
         vm_plain = _vmapped_plain_step(step_loss, opt)
@@ -476,7 +481,10 @@ class LocalTrainer:
         round-trip. Bit-identical to `train` over the equivalent iterator.
         (Pool-regularized training has no single-model scanned form; the
         whole pool procedure is `local_client_train_scanned`.)"""
-        return self.scanned_plain(params, plan.arrays, plan.take(n_steps))
+        with obs.span(obs.TAKE):
+            idx = plan.take(n_steps)
+        with obs.span(obs.DISPATCH):
+            return self.scanned_plain(params, plan.arrays, idx)
 
     # -- paper Alg. 1 lines 3–17 -------------------------------------------
 
@@ -512,8 +520,9 @@ class LocalTrainer:
         if on_model_end is None:
             # single deferred sync: every model's dispatches are already
             # queued before the first float() blocks
-            records = [ModelRecord(index=j, task_loss=float(t))
-                       for j, t in enumerate(tasks)]
+            with obs.span(obs.SYNC):
+                records = [ModelRecord(index=j, task_loss=float(t))
+                           for j, t in enumerate(tasks)]
         return pool.average(), pool, records
 
     def local_client_train_scanned(self, m_in: PyTree, plan: DataPlan,
@@ -528,13 +537,16 @@ class LocalTrainer:
         if not fed.use_pool:
             params, _ = self.train_scanned(m_in, plan, fed.e_local)
             return params, None, []
-        idx = plan.take(fed.pool_size * fed.e_local).reshape(
-            fed.pool_size, fed.e_local, plan.batch_size)
-        avg, pool, tasks = self.scanned_local(
-            m_in, plan.arrays, idx, jnp.float32(fed.alpha),
-            jnp.float32(fed.beta))
-        records = [ModelRecord(index=j, task_loss=float(t))
-                   for j, t in enumerate(np.asarray(tasks))]
+        with obs.span(obs.TAKE):
+            idx = plan.take(fed.pool_size * fed.e_local).reshape(
+                fed.pool_size, fed.e_local, plan.batch_size)
+        with obs.span(obs.DISPATCH):
+            avg, pool, tasks = self.scanned_local(
+                m_in, plan.arrays, idx, jnp.float32(fed.alpha),
+                jnp.float32(fed.beta))
+        with obs.span(obs.SYNC):
+            records = [ModelRecord(index=j, task_loss=float(t))
+                       for j, t in enumerate(np.asarray(tasks))]
         return avg, pool, records
 
     # -- batched variants (B independent runs, leading run axis) ------------

@@ -30,6 +30,8 @@ from typing import Any, Dict, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
+
 PyTree = Any
 F32 = jnp.float32
 
@@ -59,6 +61,7 @@ class ModelPool(NamedTuple):
     count: jax.Array
 
     @classmethod
+    @jax.named_scope(obs.POOL_CREATE)
     def create(cls, m0: PyTree, capacity: int) -> "ModelPool":
         stack = tree_zeros_like_stacked(m0, capacity)
         stack = tree_set_member(stack, m0, 0)
@@ -68,6 +71,7 @@ class ModelPool(NamedTuple):
     def capacity(self) -> int:
         return jax.tree.leaves(self.members)[0].shape[0]
 
+    @jax.named_scope(obs.POOL_APPEND)
     def append(self, params: PyTree) -> "ModelPool":
         return self._replace(
             members=tree_set_member(self.members, params, self.count),
@@ -76,6 +80,7 @@ class ModelPool(NamedTuple):
     def mask(self) -> jax.Array:
         return (jnp.arange(self.capacity) < self.count).astype(F32)
 
+    @jax.named_scope(obs.POOL_AVERAGE)
     def average(self) -> PyTree:
         """Eq. 5/6: masked mean over live members."""
         w = self.mask() / self.count.astype(F32)
@@ -98,11 +103,13 @@ class MomentPool(NamedTuple):
     anchor: PyTree         # m_0^i (kept exactly — d2 needs it)
 
     @classmethod
+    @jax.named_scope(obs.POOL_CREATE)
     def create(cls, m0: PyTree) -> "MomentPool":
         mean = jax.tree.map(lambda p: p.astype(F32), m0)
         q = _sq_norm(m0)
         return cls(mean, q, jnp.int32(1), m0)
 
+    @jax.named_scope(obs.POOL_APPEND)
     def append(self, params: PyTree) -> "MomentPool":
         """Left-fold incremental update: μ ← (n·μ + w)/(n+1) applied in
         append order. Mathematically this equals the stacked pool's masked
@@ -116,6 +123,7 @@ class MomentPool(NamedTuple):
         new_q = (self.sq_norm_mean * n + _sq_norm(params)) / (n + 1)
         return MomentPool(new_mean, new_q, self.count + 1, self.anchor)
 
+    @jax.named_scope(obs.POOL_AVERAGE)
     def average(self) -> PyTree:
         return jax.tree.map(lambda m, a: m.astype(a.dtype),
                             self.mean, self.anchor)
@@ -211,6 +219,7 @@ class LowRankDeltaPool(NamedTuple):
     count: jax.Array
 
     @classmethod
+    @jax.named_scope(obs.POOL_CREATE)
     def create(cls, m0: PyTree, capacity: int,
                rank: int) -> "LowRankDeltaPool":
         u, v, dense = {}, {}, {}
@@ -235,6 +244,7 @@ class LowRankDeltaPool(NamedTuple):
         """The configured rank ceiling (max per-leaf factor rank)."""
         return max([a.shape[-1] for a in self.u.values()] or [0])
 
+    @jax.named_scope(obs.POOL_APPEND)
     def append(self, params: PyTree) -> "LowRankDeltaPool":
         """Truncated-rank append: Δ = params − base, each matrix leaf
         projected onto rank r via the randomized range-finder."""
@@ -257,6 +267,7 @@ class LowRankDeltaPool(NamedTuple):
     def mask(self) -> jax.Array:
         return (jnp.arange(self.capacity) < self.count).astype(F32)
 
+    @jax.named_scope(obs.POOL_AVERAGE)
     def average(self) -> PyTree:
         """Eq. 5/6 masked mean — the ONE place factors densify on the
         training path: base + Σ_t w_t·U_tV_tᵀ, reconstructed lazily per

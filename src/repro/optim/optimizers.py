@@ -13,6 +13,8 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
+
 PyTree = Any
 F32 = jnp.float32
 
@@ -31,6 +33,7 @@ def sgd(lr: float, weight_decay: float = 0.0) -> Optimizer:
     def init(params):
         return ()
 
+    @jax.named_scope(obs.OPT)
     def update(params, grads, state, step):
         # routes through the fused local-step sweep: one blocked Pallas
         # pass over the flattened vector on TPU, the identical per-leaf
@@ -47,6 +50,7 @@ def momentum(lr: float, beta: float = 0.9,
     def init(params):
         return {"m": jax.tree.map(lambda p: jnp.zeros(p.shape, F32), params)}
 
+    @jax.named_scope(obs.OPT)
     def update(params, grads, state, step):
         def upd(p, g, m):
             g = g.astype(F32) + weight_decay * p.astype(F32)
@@ -68,6 +72,7 @@ def adam(lr: float, b1: float = 0.9, b2: float = 0.999, eps: float = 1e-8,
         z = lambda p: jnp.zeros(p.shape, F32)
         return {"m": jax.tree.map(z, params), "v": jax.tree.map(z, params)}
 
+    @jax.named_scope(obs.OPT)
     def update(params, grads, state, step):
         t = step.astype(F32) + 1.0
         c1 = 1.0 - b1 ** t
