@@ -64,10 +64,14 @@ class ArchConfig:
     sliding_window: int = 0
     # dtype for params in the dry-run / production config
     param_dtype: str = "bfloat16"
-    # activation checkpointing: recompute each scanned layer in backward.
-    # §Perf iteration 1 — the no-remat baseline stores every scan activation
-    # (O(L) blowup, ~18 TB/device for qwen2-72b train_4k); remat bounds peak
-    # temp at ~one layer's activations for a ~1.33x FLOP overhead.
+    # activation checkpointing of each scanned layer (False: none, so every
+    # scan activation is stored, ~18 TB/device for qwen2-72b train_4k).
+    # The dense/MoE/MLA decoder keeps its projection outputs for the
+    # backward where they fit in a quarter of the device's memory
+    # (`transformer.KEEP_PROJ_SHARE`), so the backward recomputes the norms,
+    # RoPE, SwiGLU and attention but no projection GEMM; otherwise, and
+    # where the device reports no memory (CPU, the dry-run), it recomputes
+    # the whole layer, ~1.33x the FLOPs. Other families always recompute.
     remat: bool = True
     source: str = ""              # citation
 

@@ -16,12 +16,20 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from repro.models.scan_util import (attn_block_override, attn_seq_shard_axes,
                                     constrain_act, gqa_repeat_mode,
                                     inner_scan)
 
 ACC = jnp.float32
+
+# The name of a weight projection's output (`_proj`, and the SwiGLU's gate
+# and up), for a checkpoint policy that keeps these outputs for the
+# backward instead of recomputing their GEMMs (`transformer.py`,
+# `decoder_remat`). Outside such a policy the name is an identity that
+# lowers to nothing.
+PROJ = "proj"
 
 
 def _he(key, shape, dtype, fan_in=None):
@@ -207,7 +215,7 @@ def _proj(x, w, b=None):
     y = jnp.einsum("btd,df->btf", x, w, preferred_element_type=ACC)
     if b is not None:
         y = y + b.astype(ACC)
-    return y.astype(x.dtype)
+    return checkpoint_name(y.astype(x.dtype), PROJ)
 
 
 def attn_qkv(p, cfg, x, positions):
@@ -313,8 +321,10 @@ def mlp_init(key, d, d_ff, dtype):
 
 def mlp(p, x):
     x = constrain_act(x)
-    g = jnp.einsum("btd,df->btf", x, p["w_gate"], preferred_element_type=ACC)
-    u = jnp.einsum("btd,df->btf", x, p["w_up"], preferred_element_type=ACC)
+    g = checkpoint_name(jnp.einsum("btd,df->btf", x, p["w_gate"],
+                                   preferred_element_type=ACC), PROJ)
+    u = checkpoint_name(jnp.einsum("btd,df->btf", x, p["w_up"],
+                                   preferred_element_type=ACC), PROJ)
     y = constrain_act(jax.nn.silu(g) * u, hidden=True)
     out = jnp.einsum("btf,fd->btd", y.astype(x.dtype), p["w_down"],
                      preferred_element_type=ACC).astype(x.dtype)

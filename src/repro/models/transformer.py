@@ -14,11 +14,13 @@ Structural choices (see DESIGN.md):
 from __future__ import annotations
 
 import functools
+import logging
 from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.configs.base import ArchConfig
 from repro.models.factored import (FACTORED_FORWARD_ATTR,
                                    make_decoder_factored)
@@ -167,6 +169,94 @@ def _stacked_init(key, cfg, n, init_one):
         jax.random.split(key, n))
 
 
+# ---------------------------------------------------------------------------
+# Activation checkpointing of the decoder layer
+# ---------------------------------------------------------------------------
+
+# The decoder layer keeps its projection outputs for the backward when they
+# take at most this share of the device's memory, and recomputes them
+# otherwise.
+KEEP_PROJ_SHARE = 0.25
+KEEP_PROJ_POLICY = jax.checkpoint_policies.save_only_these_names(L.PROJ)
+_log = logging.getLogger(__name__)
+
+
+def kept_proj_bytes(cfg: ArchConfig, batch: int, seq: int, dtype) -> int:
+    """Bytes that `KEEP_PROJ_POLICY` keeps for the backward of the
+    decoder's layers over (batch, seq) tokens: the projection outputs
+    (`layers.PROJ`) that the backward reads, or recomputes from. Those are
+    every attention projection in the activations' dtype (q, k, v and o,
+    whose residual sum the FFN's norm reads; MLA's query, latent, rope
+    key, key and value up-projections and o) and the SwiGLU's gate and up
+    as the f32 products it reads (the dense FFN's, or the MoE's shared
+    experts'; routed experts are recomputed). The FFN's down projection
+    feeds only the layer's output, which its backward does not read, so
+    it is not kept."""
+    d, h = cfg.d_model, cfg.n_heads
+    if cfg.mla:
+        m = cfg.mla
+        attn = (h * (m.qk_nope_dim + m.qk_rope_dim) + m.kv_lora_rank
+                + m.qk_rope_dim + h * m.qk_nope_dim + h * m.v_head_dim + d)
+    else:
+        hd = cfg.resolved_head_dim
+        attn = (h + 2 * cfg.n_kv_heads) * hd + d
+    if cfg.moe:
+        ffn = 2 * cfg.moe.d_ff_expert * cfg.moe.n_shared_experts
+    else:
+        ffn = 2 * cfg.d_ff
+    per_token = (attn * jnp.dtype(dtype).itemsize
+                 + ffn * jnp.dtype(ACC).itemsize)
+    return cfg.n_layers * batch * seq * per_token
+
+
+def device_bytes_limit() -> Optional[int]:
+    """The smallest memory `bytes_limit` among the default backend's local
+    devices, where the step's arrays live; None where a device reports no
+    memory (the CPU)."""
+    limits = [(d.memory_stats() or {}).get("bytes_limit")
+              for d in jax.local_devices()]
+    if not limits or None in limits:
+        return None
+    return min(limits)
+
+
+def keeps_proj(nbytes: int, bytes_limit: Optional[int]) -> bool:
+    return bytes_limit is not None and nbytes <= KEEP_PROJ_SHARE * bytes_limit
+
+
+def decoder_remat(cfg: ArchConfig, batch: int, seq: int, dtype) -> Callable:
+    """The checkpoint of one decoder layer for a step over (batch, seq)
+    tokens: one that keeps the projection outputs where their bytes fit in
+    `KEEP_PROJ_SHARE` of the device's memory, so the backward recomputes
+    the norms, RoPE, SwiGLU and attention but none of the projection
+    GEMMs; else the plain checkpoint, which recomputes the whole layer.
+    The bytes are reckoned for the whole batch on one device, which
+    over-counts a sharded batch; under `vmap` the step's batch axis is not
+    seen. The kept path runs its layers under the `obs.REMAT_KEEP` scope,
+    so the compiled step names it."""
+    nbytes = kept_proj_bytes(cfg, batch, seq, dtype)
+    limit = device_bytes_limit()
+    keep = keeps_proj(nbytes, limit)
+    _log.info("decoder %s: %s, projection outputs %d B, device limit %s B",
+              cfg.name, "keeping" if keep else "recomputing", nbytes, limit)
+    if not keep:
+        return jax.checkpoint
+
+    def remat(layer):
+        # The layer runs inside a scan, which already keeps XLA from merging
+        # the recompute into the forward. Without the checkpoint's own
+        # barriers the backward reads the kept outputs where they lie; with
+        # them it first copies each out of the scan's stack.
+        kept = jax.checkpoint(layer, policy=KEEP_PROJ_POLICY,
+                              prevent_cse=False)
+
+        def scoped(carry, lp):
+            with jax.named_scope(obs.REMAT_KEEP):
+                return kept(carry, lp)
+        return scoped
+    return remat
+
+
 def build_decoder_only(cfg: ArchConfig) -> Model:
     dtype = _dtype(cfg)
 
@@ -186,7 +276,7 @@ def build_decoder_only(cfg: ArchConfig) -> Model:
             return (x, aux + a), None
 
         if cfg.remat:
-            layer = jax.checkpoint(layer)
+            layer = decoder_remat(cfg, b, t, x.dtype)(layer)
 
         # §Perf: REPRO_REMAT_SEGMENTS=k — hierarchical (√L-style) remat.
         # Plain remat-in-scan still stashes every layer's input carry

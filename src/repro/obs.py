@@ -41,6 +41,10 @@ POOL_CREATE = "pool.create"
 POOL_AVERAGE = "pool.average"
 POOL_APPEND = "pool.append"
 SCOPES = (TASK, REG, OPT, POOL_CREATE, POOL_AVERAGE, POOL_APPEND)
+# The decoder layers of a step that keep their projection outputs for the
+# backward (`models/transformer.decoder_remat`); absent where they are
+# recomputed. Not a part of the split above: it lies inside `step.task`.
+REMAT_KEEP = "remat.keep_proj"
 
 # Host spans, each written as SPAN_PREFIX + name.
 SPAN_PREFIX = "repro."
