@@ -82,14 +82,15 @@ def model_flops(cfg, shape, n_params: int, n_active_params: Optional[int] = None
 
 
 def active_params(cfg, n_params: int) -> int:
-    """Rough active-parameter count for MoE archs (top-k of routed)."""
+    """Rough active-parameter count for MoE archs (top-k of routed, at the
+    nominal share of the held experts)."""
     if not cfg.moe:
         return n_params
     m = cfg.moe
-    routed = cfg.n_layers * 3 * cfg.d_model * m.d_ff_expert * m.n_experts
+    held = cfg.resolved_experts_held
+    routed = ((cfg.n_layers - cfg.first_k_dense) * 3 * cfg.d_model
+              * m.d_ff_expert * held)
     active_routed = routed * m.top_k / m.n_experts
-    shared = (cfg.n_layers * 3 * cfg.d_model * m.d_ff_expert
-              * m.n_shared_experts)
     return int(n_params - routed + active_routed)
 
 

@@ -11,12 +11,32 @@ from typing import Optional, Tuple
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
+    """A routed-expert FFN: a softmax router over `n_experts`, greedy top-k,
+    each chosen expert's SwiGLU weighted by its gate; `n_shared_experts`
+    SwiGLUs of width `d_ff_expert` every token passes through."""
     n_experts: int
     top_k: int
     d_ff_expert: int
     n_shared_experts: int = 0
-    capacity_factor: float = 1.25
-    router_aux_weight: float = 0.001
+    # Gates are the top-k softmax probabilities, renormalised to sum to one
+    # only where `norm_topk_prob` is set, then times `routed_scaling`.
+    norm_topk_prob: bool = False
+    routed_scaling: float = 1.0
+    # Weight of the per-sequence balance loss (DeepSeek-V2's `seq_aux`).
+    aux_loss_alpha: float = 0.001
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN scaling of RoPE (DeepSeek-V2's `rope_scaling`, type "yarn"):
+    the frequencies and the softmax scale's mscale (`layers.yarn_freqs`,
+    `layers.yarn_mscale`)."""
+    factor: float = 40.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
 
 
 @dataclasses.dataclass(frozen=True)
@@ -62,6 +82,16 @@ class ArchConfig:
     n_encoder_layers: int = 0
     # sliding-window attention (0 = full attention). Enables long_500k decode.
     sliding_window: int = 0
+    # Leading dense layers of a MoE decoder (DeepSeek's
+    # `first_k_dense_replace`), with their SwiGLU width (0: `d_ff`).
+    first_k_dense: int = 0
+    dense_d_ff: int = 0
+    # YaRN scaling of MLA's rope dims (None: plain RoPE).
+    yarn: Optional[YarnConfig] = None
+    # Routed experts this device holds, [0, experts_held) of the router's
+    # `moe.n_experts` (0: all of them); the device computes its own experts'
+    # part of the layer (one chip's share of expert parallelism).
+    experts_held: int = 0
     # dtype for params in the dry-run / production config
     param_dtype: str = "bfloat16"
     # activation checkpointing of each scanned layer (False: none, so every
@@ -78,6 +108,14 @@ class ArchConfig:
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim if self.head_dim else self.d_model // self.n_heads
+
+    @property
+    def resolved_dense_d_ff(self) -> int:
+        return self.dense_d_ff or self.d_ff
+
+    @property
+    def resolved_experts_held(self) -> int:
+        return self.experts_held or self.moe.n_experts
 
     @property
     def is_attention_free(self) -> bool:
@@ -104,12 +142,17 @@ class ArchConfig:
                   d_ff=min(512, self.d_ff), vocab_size=min(1024, self.vocab_size),
                   head_dim=d // heads, param_dtype="float32")
         if self.moe is not None:
-            kw["moe"] = MoEConfig(n_experts=min(4, self.moe.n_experts),
-                                  top_k=min(2, self.moe.top_k),
-                                  d_ff_expert=min(128, self.moe.d_ff_expert),
-                                  n_shared_experts=min(1, self.moe.n_shared_experts))
+            kw["moe"] = dataclasses.replace(
+                self.moe, n_experts=min(4, self.moe.n_experts),
+                top_k=min(2, self.moe.top_k),
+                d_ff_expert=min(128, self.moe.d_ff_expert),
+                n_shared_experts=min(1, self.moe.n_shared_experts))
         else:
             kw["moe"] = None
+        kw["experts_held"] = 0
+        kw["first_k_dense"] = min(1, self.first_k_dense)
+        kw["dense_d_ff"] = min(512, self.dense_d_ff)
+        kw["yarn"] = self.yarn
         if self.mla is not None:
             kw["mla"] = MLAConfig(kv_lora_rank=64, qk_rope_dim=16,
                                   qk_nope_dim=32, v_head_dim=32)

@@ -134,6 +134,59 @@ def bgmv(x, u, v):
     return bgmv_ref(x, u, v)
 
 
+# ---------------------------------------------------------------------------
+# Grouped matmul of the held experts (models/moe.py)
+# ---------------------------------------------------------------------------
+
+GMM_TM = 512              # rows of a tile; the rows are padded to it
+
+
+def _gmm_tile(dim: int) -> int:
+    """A contraction or output tile: the whole dim up to 1536, else 512
+    (a v5e's scoped VMEM holds the f32 accumulator and the double-buffered
+    blocks of a (512, 512, 1408) tile)."""
+    return dim if dim <= 1536 else 512
+
+
+def _gmm_tiling(m: int, k: int, n: int):
+    return (min(GMM_TM, m), _gmm_tile(k), _gmm_tile(n))
+
+
+def grouped_matmul_ref(x, w, sizes, out_dtype):
+    """The jnp twin of `grouped_matmul`: each group's product masked to its
+    rows, summed over the groups (G x the work; the CPU path and the
+    oracle)."""
+    ends = jnp.cumsum(sizes)
+    row = jnp.arange(x.shape[0])
+    member = (row[:, None] >= ends[None, :] - sizes[None, :]) & \
+        (row[:, None] < ends[None, :])                      # (M, G)
+    y = jnp.einsum("md,gdf,mg->mf", x, w, member.astype(x.dtype),
+                   preferred_element_type=jnp.float32)
+    return y.astype(out_dtype)
+
+
+def grouped_matmul(x, w, sizes, out_dtype=jnp.float32):
+    """Rows of x (M, d) in consecutive groups of `sizes` (G,) rows, each
+    times its group's w[g] (G, d, f) -> (M, f); rows past sum(sizes) are
+    zero. On TPU the megablox kernel, which visits only the tiles that
+    hold a group's rows, so its work scales with sum(sizes) and not with
+    M; elsewhere `grouped_matmul_ref`. Differentiable in x and w."""
+    if not _use_pallas():
+        return grouped_matmul_ref(x, w, sizes, out_dtype)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    m = x.shape[0]
+    tm = min(GMM_TM, m)
+    pad = -m % tm
+    if pad:
+        x = jnp.pad(x, ((0, pad), (0, 0)))
+    # One trailing group holds the rows past the held experts' (padding
+    # included); with G weight panels the kernel leaves it out.
+    rest = (m + pad - jnp.sum(sizes))[None].astype(jnp.int32)
+    y = gmm(x, w, jnp.concatenate([sizes.astype(jnp.int32), rest]),
+            out_dtype, _gmm_tiling)
+    return y[:m] if pad else y
+
+
 def fused_conv2d(x, w, b):
     """SAME stride-1 NHWC conv as im2col + blocked GEMM — forward and
     backward contain no `lax.conv`, so the op is scan-safe (no conv-in-scan

@@ -12,6 +12,7 @@ Conventions
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -60,13 +61,45 @@ def rope_freqs(head_dim, theta):
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=ACC) / head_dim))
 
 
-def apply_rope(x, positions, theta):
-    """x: (..., T, H, hd) rotated pairwise; positions: (..., T)."""
+def yarn_mscale(factor, mscale):
+    """YaRN's attention factor: 0.1 * mscale * ln(factor) + 1 (1 where the
+    context is not stretched)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_freqs(dim, theta, yarn):
+    """YaRN's inverse frequencies for a rope of `dim` dims (DeepSeek-V2's
+    `DeepseekV2YarnRotaryEmbedding`): pair i keeps theta^(-2i/dim) below
+    the correction range [low, high] and takes it divided by `factor` above
+    it, blended linearly across; low and high are the dims at which a
+    frequency turns `beta_fast` and `beta_slow` times over the original
+    context, floor and ceil of dim * ln(L / (2 pi beta)) / (2 ln theta)."""
+    def turns(beta):
+        return (dim * math.log(yarn.original_max_position
+                               / (beta * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(turns(yarn.beta_fast)), 0)
+    high = min(math.ceil(turns(yarn.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=ACC) - low) / (high - low),
+                    0.0, 1.0)
+    extra = rope_freqs(dim, theta)
+    return extra / yarn.factor * ramp + extra * (1.0 - ramp)
+
+
+def apply_rope(x, positions, theta, freqs=None, amp=1.0):
+    """x: (..., T, H, hd) rotated pairwise; positions: (..., T). `freqs`
+    (hd/2,) replaces the plain inverse frequencies and `amp` scales cos and
+    sin (YaRN)."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                       # (hd/2,)
+    if freqs is None:
+        freqs = rope_freqs(hd, theta)                   # (hd/2,)
     angles = positions.astype(ACC)[..., None] * freqs   # (..., T, hd/2)
     cos = jnp.cos(angles)[..., None, :]                 # (..., T, 1, hd/2)
     sin = jnp.sin(angles)[..., None, :]
+    if amp != 1.0:
+        cos, sin = cos * amp, sin * amp
     x1, x2 = jnp.split(x.astype(ACC), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
@@ -90,12 +123,13 @@ def _gqa_expand(q, n_kv):
 
 
 def flash_attention(q, k, v, *, causal=True, window=0, q_offset=0,
-                    kv_block=512):
+                    kv_block=512, scale=None):
     """Chunked online-softmax attention.
 
     q: (B, Tq, H, hd); k,v: (B, Tk, KV, hd). q_offset: absolute position of
     q[0] relative to k[0] (for cached decode / chunked prefill).
     window: 0 = full; >0 = attend only to keys within `window` positions.
+    scale: the scores' factor (None: hd^-1/2).
     """
     kv_block = attn_block_override(kv_block)
     if gqa_repeat_mode():
@@ -119,7 +153,7 @@ def flash_attention(q, k, v, *, causal=True, window=0, q_offset=0,
     tk, n_kv = k.shape[1], k.shape[2]
     vd = v.shape[-1]
     g = h // n_kv
-    scale = hd ** -0.5
+    scale = hd ** -0.5 if scale is None else scale
     qg = _gqa_expand(q, n_kv).astype(ACC) * scale       # (B,Tq,KV,G,hd)
 
     n_blocks = -(-tk // kv_block)
@@ -276,12 +310,32 @@ def mla_init(key, cfg, dtype):
     }
 
 
+def mla_rope(x, positions, cfg):
+    """RoPE on MLA's rope dims, YaRN-scaled where the configuration says."""
+    y = cfg.yarn
+    if y is None:
+        return apply_rope(x, positions, cfg.rope_theta)
+    return apply_rope(x, positions, cfg.rope_theta,
+                      freqs=yarn_freqs(x.shape[-1], cfg.rope_theta, y),
+                      amp=yarn_mscale(y.factor, y.mscale)
+                      / yarn_mscale(y.factor, y.mscale_all_dim))
+
+
+def mla_scale(cfg):
+    """MLA's softmax scale: qk_head_dim^-1/2, times mscale^2 under YaRN."""
+    m, y = cfg.mla, cfg.yarn
+    scale = (m.qk_nope_dim + m.qk_rope_dim) ** -0.5
+    if y is not None and y.mscale_all_dim:
+        scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
+
+
 def mla_latent(p, cfg, x, positions):
     """Compress x into the MLA cacheables: latent c_kv and shared rope key."""
     m = cfg.mla
     c_kv = rms_norm(p["kv_norm"], _proj(x, p["w_dkv"]), cfg.norm_eps)
     k_rope = _proj(x, p["w_kr"])[:, :, None, :]          # (B,T,1,rope)
-    k_rope = apply_rope(k_rope, positions, cfg.rope_theta)
+    k_rope = mla_rope(k_rope, positions, cfg)
     return c_kv, k_rope[:, :, 0, :]
 
 
@@ -297,14 +351,15 @@ def mla_attention(p, cfg, x, positions, c_kv, k_rope, *, q_offset=0,
     s = c_kv.shape[1]
     q = _proj(x, p["w_dq"]).reshape(b, t, h, m.qk_nope_dim + m.qk_rope_dim)
     q_nope, q_rope = q[..., :m.qk_nope_dim], q[..., m.qk_nope_dim:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = mla_rope(q_rope, positions, cfg)
     k_nope = _proj(c_kv, p["w_uk"]).reshape(b, s, h, m.qk_nope_dim)
     v = _proj(c_kv, p["w_uv"]).reshape(b, s, h, m.v_head_dim)
     k = jnp.concatenate(
         [k_nope, jnp.broadcast_to(k_rope[:, :, None, :], (b, s, h, m.qk_rope_dim))],
         axis=-1)
     q_full = jnp.concatenate([q_nope, q_rope], axis=-1)
-    o = flash_attention(q_full, k, v, causal=causal, q_offset=q_offset)
+    o = flash_attention(q_full, k, v, causal=causal, q_offset=q_offset,
+                        scale=mla_scale(cfg))
     return _proj(o.reshape(b, t, h * m.v_head_dim), p["wo"])
 
 

@@ -1,98 +1,130 @@
-"""Mixture-of-Experts layer: top-k router + sort-based capacity dispatch.
+"""Mixture-of-Experts layer for one device's share of expert parallelism.
 
-Dispatch strategy (TPU-native, GShard-descended but without the O(T·E·C)
-one-hot dispatch tensor): tokens are argsorted by assigned expert, ranked
-within their expert by a cumulative count, and scattered into a dense
-(E, C, D) buffer. Expert compute is a single batched einsum whose E axis is
-sharded over the `model` mesh axis (expert parallelism); GSPMD inserts the
-all-to-all at the scatter/gather boundaries. Overflow tokens beyond capacity
-C are dropped (standard Switch behaviour); the router carries a load-balance
-auxiliary loss to keep drops rare.
+The router is f32 over all `moe.n_experts` experts; each token takes the
+greedy top-k of the softmax, with the top-k probabilities as gates
+(renormalised to sum to one only where `norm_topk_prob` is set, then times
+`routed_scaling`). The device holds the first `experts_held` of the
+experts: it computes their part of the layer and nothing of the rest, as
+one chip of an expert-parallel group does before the exchange.
+
+Dispatch drops no token and has no capacity: the N*k assignments are
+sorted by expert, the held experts' first, and their rows gathered; the
+held experts run as grouped GEMMs over those rows (`kernels/ops.
+grouped_matmul`), whose work scales with the rows that reach them; the
+outputs are gathered back and summed with their gates. The shared experts
+are added once. The balance loss is DeepSeek-V2's per-sequence one, over
+all experts on this device's tokens, which every device computes alike:
+
+    aux = alpha * mean_b sum_e (E / (T k)) count_{b,e} * mean_t P_{b,t,e}
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import ACC, _he
-from repro.models.scan_util import moe_ep_constraint
+from repro import obs
+from repro.kernels import ops
+from repro.models.layers import ACC, _he, mlp, mlp_init
+
 
 def moe_init(key, cfg, dtype):
     m, d = cfg.moe, cfg.d_model
+    held, f = cfg.resolved_experts_held, m.d_ff_expert
     ks = jax.random.split(key, 5)
     p = {
         "router": _he(ks[0], (d, m.n_experts), jnp.float32),
-        "w_gate": _he(ks[1], (m.n_experts, d, m.d_ff_expert), dtype),
-        "w_up": _he(ks[2], (m.n_experts, d, m.d_ff_expert), dtype),
-        "w_down": _he(ks[3], (m.n_experts, m.d_ff_expert, d), dtype,
-                      fan_in=m.d_ff_expert),
+        "w_gate": _he(ks[1], (held, d, f), dtype, fan_in=d),
+        "w_up": _he(ks[2], (held, d, f), dtype, fan_in=d),
+        "w_down": _he(ks[3], (held, f, d), dtype, fan_in=f),
     }
     if m.n_shared_experts:
-        from repro.models.layers import mlp_init
-        p["shared"] = mlp_init(ks[4], d, m.d_ff_expert * m.n_shared_experts,
-                               dtype)
+        p["shared"] = mlp_init(ks[4], d, f * m.n_shared_experts, dtype)
     return p
 
 
-def _capacity(n_tokens, cfg):
+def route(router, cfg, x):
+    """x (B, T, D) -> gates (N, k) f32, expert ids (N, k), balance loss."""
     m = cfg.moe
-    c = int(n_tokens * m.top_k / m.n_experts * m.capacity_factor)
-    return max(8, -(-c // 8) * 8)  # pad to multiple of 8 for tiling
+    b, t, d = x.shape
+    logits = jnp.einsum("nd,de->ne", x.reshape(b * t, d).astype(ACC),
+                        router, precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, idx = jax.lax.top_k(probs, m.top_k)
+    if m.norm_topk_prob:
+        gates = gates / (gates.sum(-1, keepdims=True) + 1e-20)
+    gates = gates * m.routed_scaling
+    counts = jax.nn.one_hot(idx.reshape(b, t * m.top_k), m.n_experts,
+                            dtype=ACC).sum(1)                   # (B, E)
+    load = counts * (m.n_experts / (t * m.top_k))
+    aux = m.aux_loss_alpha * jnp.mean(jnp.sum(
+        load * probs.reshape(b, t, m.n_experts).mean(1), axis=-1))
+    return gates, idx, aux
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch(x, order, inv, k):
+    """Rows x[order // k] (each token's k assignments, sorted by expert);
+    the backward gathers the rows back and sums each token's k."""
+    return x[order // k]
+
+
+def _dispatch_fwd(x, order, inv, k):
+    return x[order // k], inv
+
+
+def _dispatch_bwd(k, inv, g):
+    gx = g[inv].reshape(g.shape[0] // k, k, -1).astype(ACC).sum(1)
+    return gx.astype(g.dtype), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(y, order, inv):
+    """Rows y[inv]: the sorted rows back in assignment order."""
+    return y[inv]
+
+
+def _combine_fwd(y, order, inv):
+    return y[inv], order
+
+
+def _combine_bwd(order, g):
+    return g[order], None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def moe_ffn(p, cfg, x):
-    """x: (B, T, D) -> (B, T, D), aux_loss scalar."""
+    """x: (B, T, D) -> (y (B, T, D), balance loss, rows routed to each held
+    expert (H,) int32), for the held experts [0, H)."""
     m = cfg.moe
     b, t, d = x.shape
-    n_tok = b * t
-    xf = x.reshape(n_tok, d)
-    cap = _capacity(n_tok, cfg)
-
-    logits = jnp.einsum("nd,de->ne", xf.astype(ACC), p["router"])
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, expert_idx = jax.lax.top_k(probs, m.top_k)     # (N, k)
-    gate_vals = gate_vals / jnp.maximum(
-        gate_vals.sum(-1, keepdims=True), 1e-9)
-
-    # Load-balance auxiliary loss (Switch): E * sum_e f_e * p_e.
-    me = probs.mean(0)
-    one_hot_top1 = jax.nn.one_hot(expert_idx[:, 0], m.n_experts)
-    ce = one_hot_top1.mean(0)
-    aux = m.n_experts * jnp.sum(me * ce)
-
-    # ---- sort-based dispatch --------------------------------------------
-    flat_expert = expert_idx.reshape(-1)                      # (N·k,)
-    flat_gate = gate_vals.reshape(-1)
-    flat_tok = jnp.repeat(jnp.arange(n_tok), m.top_k)
-    order = jnp.argsort(flat_expert)                          # stable
-    se, sg, st = flat_expert[order], flat_gate[order], flat_tok[order]
-    # rank within expert = position - first position of that expert
-    pos = jnp.arange(se.shape[0])
-    seg_start = jnp.searchsorted(se, jnp.arange(m.n_experts))
-    rank = pos - seg_start[se]
-    keep = rank < cap
-    slot = jnp.where(keep, se * cap + rank, m.n_experts * cap)  # overflow slot
-
-    buf = jnp.zeros((m.n_experts * cap + 1, d), x.dtype)
-    buf = buf.at[slot].set(xf[st])                            # scatter
-    buf = buf[:-1].reshape(m.n_experts, cap, d)
-    if moe_ep_constraint():
-        from jax.sharding import PartitionSpec as _P
-        buf = jax.lax.with_sharding_constraint(buf, _P("model", None, None))
-
-    # ---- expert compute (E axis expert-parallel) ------------------------
-    g = jnp.einsum("ecd,edf->ecf", buf, p["w_gate"], preferred_element_type=ACC)
-    u = jnp.einsum("ecd,edf->ecf", buf, p["w_up"], preferred_element_type=ACC)
-    h = (jax.nn.silu(g) * u).astype(x.dtype)
-    out_buf = jnp.einsum("ecf,efd->ecd", h, p["w_down"],
-                         preferred_element_type=ACC).astype(x.dtype)
-
-    # ---- combine ---------------------------------------------------------
-    out_flat = out_buf.reshape(m.n_experts * cap, d)
-    gathered = jnp.where(keep[:, None], out_flat[jnp.clip(slot, 0, m.n_experts * cap - 1)], 0.0)
-    y = jnp.zeros((n_tok, d), ACC).at[st].add(gathered.astype(ACC) * sg[:, None])
-
+    n, k, held = b * t, m.top_k, p["w_gate"].shape[0]
+    with jax.named_scope(obs.MOE_ROUTE):
+        gates, idx, aux = route(p["router"], cfg, x)
+    with jax.named_scope(obs.MOE_DISPATCH):
+        slot = idx.reshape(-1)                      # held experts first
+        order = jnp.argsort(slot, stable=True).astype(jnp.int32)
+        inv = jnp.zeros_like(order).at[order].set(
+            jnp.arange(n * k, dtype=jnp.int32))
+        sizes = jnp.sum(slot[:, None] == jnp.arange(held)[None, :], axis=0,
+                        dtype=jnp.int32)
+        rows = _dispatch(x.reshape(n, d), order, inv, k)
+    with jax.named_scope(obs.MOE_EXPERTS):
+        g = ops.grouped_matmul(rows, p["w_gate"], sizes)
+        u = ops.grouped_matmul(rows, p["w_up"], sizes)
+        h = (jax.nn.silu(g) * u).astype(x.dtype)
+        out = ops.grouped_matmul(h, p["w_down"], sizes, x.dtype)
+    with jax.named_scope(obs.MOE_DISPATCH):
+        back = _combine(out, order, inv).reshape(n, k, d)
+        w = jnp.where(slot.reshape(n, k) < held, gates, 0.0)
+        y = jnp.einsum("nkd,nk->nd", back.astype(ACC), w)
     if m.n_shared_experts:
-        from repro.models.layers import mlp
-        y = y + mlp(p["shared"], x).reshape(n_tok, d).astype(ACC)
-    return y.reshape(b, t, d).astype(x.dtype), aux * m.router_aux_weight
+        with jax.named_scope(obs.MOE_SHARED):
+            y = y + mlp(p["shared"], x).reshape(n, d).astype(ACC)
+    return y.reshape(b, t, d).astype(x.dtype), aux, sizes

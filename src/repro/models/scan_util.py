@@ -59,14 +59,6 @@ def gqa_repeat_mode() -> bool:
     return os.environ.get("REPRO_GQA_REPEAT", "") == "1"
 
 
-def moe_ep_constraint() -> bool:
-    """REPRO_MOE_EP_CONSTRAINT=1: pin the dispatched (E, C, D) buffer to
-    expert-parallel sharding so GSPMD lowers dispatch/combine as all-to-all
-    rather than gather+dynamic-slice chains."""
-    return os.environ.get("REPRO_MOE_EP_CONSTRAINT", "") == "1"
-
-
-
 def act_shard_axes():
     """REPRO_ACT_SHARD: '' | 'single' | 'multi' — pin layer activations to
     batch-sharded layout (MaxText-style constraints). Without it GSPMD may

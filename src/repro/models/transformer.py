@@ -58,6 +58,9 @@ class Model(NamedTuple):
     prefill: Optional[Callable]          # (params, batch) -> (logits, cache)
     decode: Optional[Callable]           # (params, token, cache, pos) -> (logits, cache)
     init_cache: Optional[Callable]       # (batch, seq_len, dtype) -> cache pytree
+    # MoE decoders: (params, batch) -> rows routed to each held expert of
+    # each MoE layer (MoE layers, experts held), forward only.
+    route_counts: Optional[Callable] = None
 
 
 def lm_eval_fn(model: "Model", test_batch: Dict[str, jax.Array]) -> Callable:
@@ -127,26 +130,29 @@ def chunked_xent(params, cfg, h, labels):
 # Decoder block bodies (dense / moe / mla variants)
 # ---------------------------------------------------------------------------
 
-def _block_init(key, cfg, dtype):
+def _block_init(key, cfg, dtype, d_ff=None):
+    """One decoder layer: attention, and a SwiGLU of width `d_ff` or, where
+    `d_ff` is None, the MoE layer."""
     ks = jax.random.split(key, 4)
     p = {"ln1": L.rms_norm_init(cfg.d_model, dtype),
          "ln2": L.rms_norm_init(cfg.d_model, dtype)}
     p["attn"] = (L.mla_init(ks[0], cfg, dtype) if cfg.mla
                  else L.attn_init(ks[0], cfg, dtype))
-    if cfg.moe:
+    if d_ff is None:
         p["ffn"] = MOE.moe_init(ks[1], cfg, dtype)
     else:
-        p["ffn"] = L.mlp_init(ks[1], cfg.d_model, cfg.d_ff, dtype)
+        p["ffn"] = L.mlp_init(ks[1], cfg.d_model, d_ff, dtype)
     return p
 
 
 def _block_ffn(p, cfg, x):
+    """-> (x, balance loss, rows routed to each held expert or None)."""
     h = L.rms_norm(p["ln2"], x, cfg.norm_eps)
-    if cfg.moe:
-        y, aux = MOE.moe_ffn(p["ffn"], cfg, h)
+    if "router" in p["ffn"]:
+        y, aux, sizes = MOE.moe_ffn(p["ffn"], cfg, h)
     else:
-        y, aux = L.mlp(p["ffn"], h), 0.0
-    return x + y, aux
+        y, aux, sizes = L.mlp(p["ffn"], h), 0.0, None
+    return x + y, aux, sizes
 
 
 def _block_fwd(p, cfg, x, positions):
@@ -158,6 +164,17 @@ def _block_fwd(p, cfg, x, positions):
         a = L.self_attention(p["attn"], cfg, h, positions)
     x = x + a
     return _block_ffn(p, cfg, x)
+
+
+def layer_groups(cfg: ArchConfig):
+    """The decoder's stacked layer groups, in order: (params key, layers,
+    SwiGLU width or None for the MoE layer). A MoE decoder's leading dense
+    layers (`first_k_dense`, of width `dense_d_ff`) are a group of their
+    own, scanned before the MoE layers."""
+    k = cfg.first_k_dense if cfg.moe else 0
+    groups = (("dense_layers", k, cfg.resolved_dense_d_ff),
+              ("layers", cfg.n_layers - k, None if cfg.moe else cfg.d_ff))
+    return [g for g in groups if g[1]]
 
 
 # ---------------------------------------------------------------------------
@@ -191,7 +208,8 @@ def kept_proj_bytes(cfg: ArchConfig, batch: int, seq: int, dtype) -> int:
     as the f32 products it reads (the dense FFN's, or the MoE's shared
     experts'; routed experts are recomputed). The FFN's down projection
     feeds only the layer's output, which its backward does not read, so
-    it is not kept."""
+    it is not kept. Each layer group (`layer_groups`) counts with its own
+    FFN width."""
     d, h = cfg.d_model, cfg.n_heads
     if cfg.mla:
         m = cfg.mla
@@ -200,13 +218,15 @@ def kept_proj_bytes(cfg: ArchConfig, batch: int, seq: int, dtype) -> int:
     else:
         hd = cfg.resolved_head_dim
         attn = (h + 2 * cfg.n_kv_heads) * hd + d
-    if cfg.moe:
-        ffn = 2 * cfg.moe.d_ff_expert * cfg.moe.n_shared_experts
-    else:
-        ffn = 2 * cfg.d_ff
-    per_token = (attn * jnp.dtype(dtype).itemsize
-                 + ffn * jnp.dtype(ACC).itemsize)
-    return cfg.n_layers * batch * seq * per_token
+    total = 0
+    for _, n, d_ff in layer_groups(cfg):
+        if d_ff is None:
+            ffn = 2 * cfg.moe.d_ff_expert * cfg.moe.n_shared_experts
+        else:
+            ffn = 2 * d_ff
+        total += n * (attn * jnp.dtype(dtype).itemsize
+                      + ffn * jnp.dtype(ACC).itemsize)
+    return total * batch * seq
 
 
 def device_bytes_limit() -> Optional[int]:
@@ -259,21 +279,28 @@ def decoder_remat(cfg: ArchConfig, batch: int, seq: int, dtype) -> Callable:
 
 def build_decoder_only(cfg: ArchConfig) -> Model:
     dtype = _dtype(cfg)
+    groups = layer_groups(cfg)
 
     def init(key):
         k1, k2 = jax.random.split(key)
-        return {**_embed_init(k1, cfg, dtype),
-                "layers": _stacked_init(k2, cfg, cfg.n_layers, _block_init)}
+        p = _embed_init(k1, cfg, dtype)
+        for name, n, d_ff in groups:
+            kg = k2 if name == "layers" else jax.random.fold_in(k2, 1)
+            p[name] = _stacked_init(kg, cfg, n, functools.partial(
+                _block_init, d_ff=d_ff))
+        return p
 
-    def backbone(params, tokens):
+    def backbone(params, tokens, collect=False):
+        """-> (x, the layers' balance loss, and with `collect` the rows
+        routed to each held expert of each MoE layer (n_moe, H))."""
         b, t = tokens.shape
         x = jnp.take(params["embed"], tokens, axis=0)
         positions = jnp.broadcast_to(jnp.arange(t), (b, t))
 
         def layer(carry, lp):
             x, aux = carry
-            x, a = _block_fwd(lp, cfg, x, positions)
-            return (x, aux + a), None
+            x, a, sizes = _block_fwd(lp, cfg, x, positions)
+            return (x, aux + a), (sizes if collect else None)
 
         if cfg.remat:
             layer = decoder_remat(cfg, b, t, x.dtype)(layer)
@@ -284,20 +311,26 @@ def build_decoder_only(cfg: ArchConfig) -> Model:
         # recomputes each segment (inner layers re-checkpointed) — carry
         # stash drops L/k× for one extra forward.
         n_seg = int(_os.environ.get("REPRO_REMAT_SEGMENTS", "1"))
-        init = (x, jnp.zeros((), ACC))
-        if n_seg > 1 and cfg.n_layers % n_seg == 0:
-            per = cfg.n_layers // n_seg
-            seg_params = jax.tree.map(
-                lambda a: a.reshape(n_seg, per, *a.shape[1:]),
-                params["layers"])
+        carry, counts = (x, jnp.zeros((), ACC)), []
+        for name, n, _ in groups:
+            if n_seg > 1 and n % n_seg == 0 and not collect:
+                per = n // n_seg
+                seg_params = jax.tree.map(
+                    lambda a: a.reshape(n_seg, per, *a.shape[1:]),
+                    params[name])
 
-            def segment(carry, sp):
-                out, _ = _scan(layer, carry, sp)
-                return out, None
+                def segment(carry, sp):
+                    out, _ = _scan(layer, carry, sp)
+                    return out, None
 
-            (x, aux), _ = _scan(jax.checkpoint(segment), init, seg_params)
-        else:
-            (x, aux), _ = _scan(layer, init, params["layers"])
+                carry, _ = _scan(jax.checkpoint(segment), carry, seg_params)
+            else:
+                carry, sizes = _scan(layer, carry, params[name])
+                if sizes is not None:
+                    counts.append(sizes)
+        x, aux = carry
+        if collect:
+            return x, aux, jnp.concatenate(counts)
         return x, aux
 
     def forward(params, batch):
@@ -315,6 +348,12 @@ def build_decoder_only(cfg: ArchConfig) -> Model:
     def loss_fn(params, batch):
         x, aux = backbone(params, batch["tokens"])
         return chunked_xent(params, cfg, x, batch["labels"]) + aux
+
+    def route_counts(params, batch):
+        """Forward only: the rows routed to each held expert of each MoE
+        layer, (MoE layers, experts held) int32, as the layers' grouped
+        GEMMs take them."""
+        return backbone(params, batch["tokens"], collect=True)[2]
 
     # ---- serving ---------------------------------------------------------
     kv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
@@ -355,11 +394,15 @@ def build_decoder_only(cfg: ArchConfig) -> Model:
                     q, k, v, causal=True, window=window))
                 kv_out = (k, v)
             x = x + a
-            x, a2 = _block_ffn(lp, cfg, x)
+            x, a2, _ = _block_ffn(lp, cfg, x)
             return (x, aux + a2), kv_out
 
-        (x, _), kvs = _scan(layer, (x, jnp.zeros((), ACC)),
-                                   params["layers"])
+        carry, per_group = (x, jnp.zeros((), ACC)), []
+        for name, _, _ in groups:
+            carry, kvs = _scan(layer, carry, params[name])
+            per_group.append(kvs)
+        x = carry[0]
+        kvs = _join(per_group)
         logits = lm_logits(params, cfg, x[:, -1:])
         if cfg.mla:
             cache = {"c_kv": kvs[0], "k_rope": kvs[1]}
@@ -403,7 +446,7 @@ def build_decoder_only(cfg: ArchConfig) -> Model:
                 a = _mla_decode_attn(lp["attn"], cfg, h, positions,
                                      c_kv_l, k_rope_l, entry_pos, pos)
                 x = x + a
-                x, _ = _block_ffn(lp, cfg, x)
+                x, _, _ = _block_ffn(lp, cfg, x)
                 return (x,), (c_kv_l, k_rope_l)
             lp, k_l, v_l = xs
             h = L.rms_norm(lp["ln1"], x, cfg.norm_eps)
@@ -415,14 +458,20 @@ def build_decoder_only(cfg: ArchConfig) -> Model:
             a = L.decode_attention(q, k_l, v_l, entry_pos,
                                    jnp.broadcast_to(pos, (b,)), window=window)
             x = x + L.attn_out(lp["attn"], a)
-            x, _ = _block_ffn(lp, cfg, x)
+            x, _, _ = _block_ffn(lp, cfg, x)
             return (x,), (k_l, v_l)
 
-        if cfg.mla:
-            xs = (params["layers"], cache["c_kv"], cache["k_rope"])
-        else:
-            xs = (params["layers"], cache["k"], cache["v"])
-        (x,), new = _scan(layer, (x,), xs)
+        names = ("c_kv", "k_rope") if cfg.mla else ("k", "v")
+        carry, per_group, start = (x,), [], 0
+        for name, n, _ in groups:
+            part = (lambda c: c) if len(groups) == 1 else (
+                lambda c: c[start:start + n])
+            carry, new = _scan(layer, carry, (params[name],) + tuple(
+                part(cache[c]) for c in names))
+            per_group.append(new)
+            start += n
+        x, = carry
+        new = _join(per_group)
         logits = lm_logits(params, cfg, x)
         if cfg.mla:
             cache = {"c_kv": new[0], "k_rope": new[1]}
@@ -430,7 +479,16 @@ def build_decoder_only(cfg: ArchConfig) -> Model:
             cache = {"k": new[0], "v": new[1]}
         return logits, cache
 
-    return Model(cfg, init, forward, loss_fn, prefill, decode, init_cache)
+    return Model(cfg, init, forward, loss_fn, prefill, decode, init_cache,
+                 route_counts if cfg.moe else None)
+
+
+def _join(per_group):
+    """Per-layer outputs of consecutive layer groups, joined on the layer
+    axis."""
+    if len(per_group) == 1:
+        return per_group[0]
+    return jax.tree.map(lambda *a: jnp.concatenate(a), *per_group)
 
 
 def _mla_decode_attn(p, cfg, h, positions, c_kv, k_rope, entry_pos, pos):
@@ -446,14 +504,13 @@ def _mla_decode_attn(p, cfg, h, positions, c_kv, k_rope, entry_pos, pos):
     q = L._proj(h, p["w_dq"]).reshape(b, 1, cfg.n_heads,
                                       m.qk_nope_dim + m.qk_rope_dim)
     q_nope, q_rope = q[..., :m.qk_nope_dim], q[..., m.qk_nope_dim:]
-    q_rope = L.apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = L.mla_rope(q_rope, positions, cfg)
     k_nope = L._proj(c_kv, p["w_uk"]).reshape(b, s, cfg.n_heads, m.qk_nope_dim)
     v = L._proj(c_kv, p["w_uv"]).reshape(b, s, cfg.n_heads, m.v_head_dim)
     k = jnp.concatenate([k_nope, jnp.broadcast_to(
         k_rope[:, :, None, :], (b, s, cfg.n_heads, m.qk_rope_dim))], axis=-1)
     qf = jnp.concatenate([q_nope, q_rope], axis=-1).astype(ACC)
-    scale = (m.qk_nope_dim + m.qk_rope_dim) ** -0.5
-    sc = jnp.einsum("bthd,bshd->bths", qf * scale, k.astype(ACC))
+    sc = jnp.einsum("bthd,bshd->bths", qf * L.mla_scale(cfg), k.astype(ACC))
     sc = jnp.where(valid[None, None, None, :], sc, L.NEG_INF)
     pr = jax.nn.softmax(sc, axis=-1)
     o = jnp.einsum("bths,bshd->bthd", pr, v.astype(ACC)).astype(h.dtype)

@@ -45,6 +45,15 @@ SCOPES = (TASK, REG, OPT, POOL_CREATE, POOL_AVERAGE, POOL_APPEND)
 # backward (`models/transformer.decoder_remat`); absent where they are
 # recomputed. Not a part of the split above: it lies inside `step.task`.
 REMAT_KEEP = "remat.keep_proj"
+# A MoE layer's work (`models/moe.py`), also inside `step.task`: the router,
+# its top-k and balance loss; the permutation of the assignments to and
+# from the held experts; the held experts' grouped GEMMs; the shared
+# experts.
+MOE_ROUTE = "moe.route"
+MOE_DISPATCH = "moe.dispatch"
+MOE_EXPERTS = "moe.experts"
+MOE_SHARED = "moe.shared"
+MOE_SCOPES = (MOE_ROUTE, MOE_DISPATCH, MOE_EXPERTS, MOE_SHARED)
 
 # Host spans, each written as SPAN_PREFIX + name.
 SPAN_PREFIX = "repro."

@@ -91,7 +91,9 @@ _RULES = [
     ("fc1", -1, -2), ("fc2", -2, -1), ("c1", None, None),
 ]
 
-# MoE expert stacks: (.., E, d, f) — expert-parallel over model axis.
+# MoE expert stacks: (L, E, d, f) under a decoder's stacked "layers" —
+# expert-parallel over model axis. A stacked dense FFN (L, d, f), as in a
+# MoE decoder's leading "dense_layers", is not one, and takes _RULES.
 _EXPERT_KEYS = ("ffn/w_gate", "ffn/w_up", "ffn/w_down")
 
 
@@ -104,8 +106,8 @@ def _leaf_spec(path: str, shape, mesh: Mesh, fsdp: bool) -> P:
     fsdp_n = _axsize(mesh, fsdp_ax)
     spec = [None] * nd
 
-    # expert-parallel: shard the expert axis (dim -3 of (E, d, f) stacks)
-    if any(k in path for k in _EXPERT_KEYS) and "shared" not in path and nd >= 3:
+    # expert-parallel: shard the expert axis (dim -3 of (L, E, d, f) stacks)
+    if any(k in path for k in _EXPERT_KEYS) and "shared" not in path and nd >= 4:
         e_dim = nd - 3
         if shape[e_dim] % model_n == 0:
             spec[e_dim] = "model"

@@ -1,7 +1,6 @@
 """Per-assigned-architecture smoke tests: a REDUCED variant of the same
 family (≤2 layers, d_model≤256, ≤4 experts) runs one forward + one train
 step on CPU; output shapes asserted, no NaNs (deliverable f)."""
-import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -81,10 +80,6 @@ def test_one_train_step_reduces_loss_and_is_finite(name, built):
 def test_serve_roundtrip(name, built):
     """prefill(T-1) + decode(1) ≈ forward(T) at the last position."""
     cfg, model, params = built(name)
-    if cfg.moe:   # capacity drops are shape-dependent; widen capacity
-        cfg = dataclasses.replace(
-            cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=8.0))
-        model = build_model(cfg)
     batch = _batch(cfg)
     tokens = batch["tokens"]
     full = model.forward(params, batch)

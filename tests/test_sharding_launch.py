@@ -41,16 +41,23 @@ def test_param_specs_shard_big_matrices():
     assert all(a is None for a in norm)
 
 
-def test_moe_expert_axis_is_expert_parallel():
-    cfg = get_arch("qwen3-moe-235b-a22b")
+@pytest.mark.parametrize("arch", ["qwen3-moe-235b-a22b",
+                                  "deepseek-v2-lite-16b"])
+def test_moe_expert_axis_is_expert_parallel(arch):
+    cfg = get_arch(arch)
     shapes = S.param_specs_for(cfg)
     specs = param_specs(shapes, _fake_mesh())
     flat = {"/".join(str(getattr(p, "key", p)) for p in path): s
             for path, s in jax.tree_util.tree_flatten_with_path(
                 specs, is_leaf=lambda x: isinstance(x, P))[0]}
-    w_gate = next(v for k, v in flat.items() if k.endswith("ffn/w_gate"))
+    w_gate = flat["layers/ffn/w_gate"]
     # (L, E, d, f): expert axis sharded over model
-    assert w_gate[1] == "model"
+    assert w_gate[1] == "model" and w_gate[0] is None
+    # a leading dense layer's stacked FFN (L, d, f) is no expert stack: its
+    # layer axis stays whole and its width takes the model axis
+    for key, spec in flat.items():
+        if key.startswith("dense_layers/ffn/"):
+            assert spec[0] is None and "model" in tuple(spec), (key, spec)
 
 
 def test_batch_specs_data_parallel():

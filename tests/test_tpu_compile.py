@@ -10,7 +10,11 @@ kernel may use, programs that do not fit the device — at no chip time.
   (2048→128256, the d_out the output tiling exists for);
 * `matmul_blocked`, forward and custom-VJP backward, at the paper CNN's
   im2col GEMM shapes (batch 32, 32×32×3 inputs, widths 64/128/256);
-* `sgd_update_flat` over the paper CNN's flattened parameter vector.
+* `sgd_update_flat` over the paper CNN's flattened parameter vector;
+* the held experts' grouped GEMM (`ops.grouped_matmul`, the megablox
+  kernel), the SwiGLU's gate, up and down and their backward, at
+  DeepSeek-V2-Lite's widths and the deepseek-v2-lite.silo_train cell's
+  rows (8 x 2048 tokens x top-6, 8 experts held).
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
@@ -103,3 +107,26 @@ def test_sgd_update_flat_compiles(one_chip):
         return sgd_update_flat(p, g, lr=1e-3, wd=1e-4)
 
     _assert_mosaic(jax.jit(step).lower(p, p).compile())
+
+
+def test_grouped_matmul_swiglu_and_vjp_compile_at_deepseek_widths(
+        one_chip, monkeypatch):
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_use_pallas", lambda: True)
+    rows, d, f, held = 8 * 2048 * 6, 2048, 1408, 8
+    x = _spec((rows, d), jnp.bfloat16, one_chip)
+    w_in = _spec((held, d, f), jnp.bfloat16, one_chip)
+    w_out = _spec((held, f, d), jnp.bfloat16, one_chip)
+    sizes = _spec((held,), jnp.int32, one_chip)
+
+    def loss(x, wg, wu, wd, sizes):
+        g = ops.grouped_matmul(x, wg, sizes)
+        u = ops.grouped_matmul(x, wu, sizes)
+        h = (jax.nn.silu(g) * u).astype(x.dtype)
+        y = ops.grouped_matmul(h, wd, sizes, x.dtype).astype(jnp.float32)
+        return (y * y).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+        x, w_in, w_in, w_out, sizes).compile()
+    # forward 3, input gradients 3, weight gradients 3
+    assert compiled.as_text().count("tpu_custom_call") >= 9
