@@ -15,8 +15,10 @@ Phase B, llama3.2-1b at its published widths (d_model 2048, d_ff 8192,
 vocab 128256, GQA 32/8, bf16 params), depth cut only as far as one chip's
 memory forces: one `fedelmy` client with the low-rank pool, the pool served
 in factor form (`factored=True`: shared base forward plus tiled BGMV
-corrections, the tied unembed included). Checked: the factored scores
-against a reference that densifies one member at a time.
+corrections, the tied unembed included). Its 128-token sequences are a
+block of the splash attention kernel, so the local phase's attention and
+the factored forward's run it. Checked: the factored scores against a
+reference that densifies one member at a time.
 
 --chips 4, the fleet cohort under `shard_map` over four chips
 (`launch(FleetSpec, mesh=make_cohort_mesh(cohort))`), against the same run
@@ -290,7 +292,7 @@ def reckon_depth(full: int, need, limit: float):
     return n, seen
 
 
-def phase_llama(*, cfg=None, hbm_limit=None, seq_len=64, batch=4, n_seqs=64,
+def phase_llama(*, cfg=None, hbm_limit=None, seq_len=128, batch=4, n_seqs=64,
                 pool_size=2, e_local=3, e_warmup=2, rank=8, n_queries=16,
                 sizes=(1, 5, 8), seed=0) -> dict:
     cfg = cfg or get_arch("llama3.2-1b")

@@ -141,16 +141,6 @@ def gemm_intensity(bm: int = 128, bk: int = 128, bn: int = 128,
     return _entry("gemm", flops, byts, f"bm={bm},bk={bk},bn={bn}")
 
 
-def flash_attention_intensity(bq: int = 128, bk: int = 128, hd: int = 64,
-                              itemsize: int = 4) -> dict:
-    """One (bq, bk) tile of `flash_attention_pallas` per head: the QKᵀ
-    score GEMM plus the PV accumulate (2·2·bq·bk·hd FLOPs) over the q, k,
-    v tiles and the (bq, hd) output accumulator."""
-    flops = 4.0 * bq * bk * hd
-    byts = float(bq * hd + 2 * bk * hd + bq * hd) * itemsize
-    return _entry("flash_attention", flops, byts, f"bq={bq},bk={bk},hd={hd}")
-
-
 def bgmv_intensity(block_n: int = 256, d_in: int = 2048, d_out: int = 2048,
                    r: int = 8, itemsize: int = 4) -> dict:
     """One (member, N-block) step of `bgmv.bgmv_pallas` (factored-serving
@@ -171,4 +161,4 @@ def kernel_intensities() -> list:
     """The repo's Pallas kernels at their default tile shapes — the
     EXPERIMENTS.md §Roofline kernel table (benchmarks/roofline_report.py
     prints and persists these rows)."""
-    return [gemm_intensity(), flash_attention_intensity(), bgmv_intensity()]
+    return [gemm_intensity(), bgmv_intensity()]

@@ -7,8 +7,7 @@ Each kernel ships three pieces (see EXAMPLE.md):
 """
 from repro.kernels import ops, ref
 from repro.kernels.chunk_scan import gla_chunk_pallas
-from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.pool_distance import factor_gram, pool_distance_stats
 
-__all__ = ["ops", "ref", "flash_attention_pallas", "pool_distance_stats",
-           "factor_gram", "gla_chunk_pallas"]
+__all__ = ["ops", "ref", "pool_distance_stats", "factor_gram",
+           "gla_chunk_pallas"]

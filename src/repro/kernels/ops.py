@@ -16,7 +16,6 @@ import jax.numpy as jnp
 
 from repro.kernels.bgmv import bgmv_pallas
 from repro.kernels.chunk_scan import gla_chunk_pallas
-from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.local_step import conv2d_gemm, maxpool2x2, sgd_update_tree
 from repro.kernels.pool_distance import (distances_from_stats, factor_gram,
                                          pool_distance_stats)
@@ -36,12 +35,6 @@ def _interpret() -> bool:
     """Interpret mode for the kernels that always run as Pallas: off-TPU
     only."""
     return not _use_pallas()
-
-
-@functools.partial(jax.jit, static_argnames=("causal", "window", "bq", "bk"))
-def flash_attention(q, k, v, *, causal=True, window=0, bq=128, bk=128):
-    return flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                  bq=bq, bk=bk, interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("measure",))
@@ -185,6 +178,99 @@ def grouped_matmul(x, w, sizes, out_dtype=jnp.float32):
     y = gmm(x, w, jnp.concatenate([sizes.astype(jnp.int32), rest]),
             out_dtype, _gmm_tiling)
     return y[:m] if pad else y
+
+
+# ---------------------------------------------------------------------------
+# Self-attention of a whole sequence (models/layers.flash_attention)
+# ---------------------------------------------------------------------------
+
+# Query and key block sizes, largest first: a call takes the first that
+# divides its sequence.
+ATTN_BLOCKS = (512, 256, 128)
+# `jax_default_matmul_precision` values under which the jnp scan's f32
+# products are single bf16 passes, as the kernel's are.
+_ONE_PASS = (None, "default", "bfloat16")
+
+
+def attention_fits(q, k, v, *, window: int, q_offset: int) -> bool:
+    """Whether `attention` takes a `layers.flash_attention` call: on TPU,
+    a whole sequence attending to itself (q_offset 0, Tq == Tk, a multiple
+    of the smallest block) under no window shorter than it, with bf16 q, k
+    and v, where the configuration leaves products at one bf16 pass."""
+    t = k.shape[1]
+    return (_use_pallas() and q_offset == 0 and q.shape[1] == t
+            and t % ATTN_BLOCKS[-1] == 0 and (not window or window >= t)
+            and all(x.dtype == jnp.bfloat16 for x in (q, k, v))
+            and jax.config.jax_default_matmul_precision in _ONE_PASS)
+
+
+@functools.cache
+def _splash_lib():
+    """jax's splash attention package, its kernels' custom calls carrying
+    no kernel metadata.
+
+    The splash kernels hand `pallas_call` their block sizes as profiler
+    metadata, which the compiled program prints as a multi-line attribute
+    ahead of the op's `metadata={op_name=...}`: the instruction then spans
+    three lines of HLO text and a line-wise reader of the compiled step
+    (`bench/scopes.py`) finds the kernels under no `obs` scope. The kernel
+    module's `pallas_call` is replaced, once per process, by one that
+    drops that metadata; the kernels, their grid and their
+    `backend_config` are unchanged."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas.ops.tpu import splash_attention as sa
+    from jax.experimental.pallas.ops.tpu.splash_attention import \
+        splash_attention_kernel as kernel_module
+
+    class _Pallas:
+        def __getattr__(self, name):
+            return getattr(pl, name)
+
+        @staticmethod
+        def pallas_call(*args, metadata=None, **kwargs):
+            return pl.pallas_call(*args, **kwargs)
+
+    kernel_module.pl = _Pallas()
+    return sa
+
+
+@functools.cache
+def _splash(heads: int, seq: int, causal: bool, interpret: bool):
+    """The splash kernel for `heads` query heads over `seq` tokens, its
+    block mask and block sizes built once per shape."""
+    sa = _splash_lib()
+    b = next(b for b in ATTN_BLOCKS if seq % b == 0)
+    blocks = sa.BlockSizes(block_q=b, block_kv=b, block_q_dkv=b,
+                           block_kv_dkv=b, block_q_dq=b, block_kv_dq=b)
+    mask = sa.CausalMask((seq, seq)) if causal else sa.FullMask((seq, seq))
+    with jax.ensure_compile_time_eval():
+        return sa.make_splash_mha_single_device(
+            sa.MultiHeadMask((mask,) * heads), block_sizes=blocks,
+            interpret=interpret)
+
+
+def attention(q, k, v, *, causal=True, scale=None):
+    """softmax(scale q kᵀ) v over one sequence, by jax's splash attention
+    kernels, forward and backward: a causal call visits only the blocks on
+    or below the diagonal, and the backward keeps the forward's output and
+    log-sum-exp, not a residual per block. q (B, T, H, hd); k (B, T, KV,
+    hd) and v (B, T, KV, vd) with H a multiple of KV (grouped queries read
+    their KV head, never a repeated copy); scale None is hd^-1/2. q is
+    scaled in f32 and rounded to its dtype; products take bf16 operands
+    into f32, and the probabilities meet v in f32. Returns (B, T, H, vd)
+    in q's dtype. Called from inside compiled programs, so no jit wrapper;
+    interpret mode off-TPU (the tests' route: `layers.flash_attention`
+    takes this only where `attention_fits`)."""
+    b, t, h, hd = q.shape
+    scale = hd ** -0.5 if scale is None else scale
+    q = (q.astype(jnp.float32) * scale).astype(q.dtype)
+    kernel = _splash(h, t, causal, _interpret())
+
+    def heads_first(x):             # (B, T, N, d) <-> (B, N, T, d)
+        return x.transpose(0, 2, 1, 3)
+
+    o = jax.vmap(kernel)(heads_first(q), heads_first(k), heads_first(v))
+    return heads_first(o)
 
 
 def fused_conv2d(x, w, b):

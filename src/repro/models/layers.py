@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from repro import obs
+from repro.kernels import ops
 from repro.models.scan_util import (attn_block_override, attn_seq_shard_axes,
                                     constrain_act, gqa_repeat_mode,
                                     inner_scan)
@@ -108,9 +110,11 @@ def apply_rope(x, positions, theta, freqs=None, amp=1.0):
 # ---------------------------------------------------------------------------
 # Attention (GQA, optional sliding window) — chunked "flash" formulation.
 #
-# The Pallas kernel in repro.kernels.flash_attention is the TPU-target
-# implementation of the same math; this jnp version is the oracle and the
-# CPU/dry-run lowering path (identical FLOPs; see DESIGN.md §5).
+# On TPU, self-attention of a whole bf16 sequence runs jax's splash
+# attention kernels, forward and backward, through `kernels/ops.attention`.
+# The jnp scan below is the path for every other call (cached decode,
+# cross-attention, short windows, f32 models, sharded sequences), the CPU
+# and dry-run path, and the kernel's oracle.
 # ---------------------------------------------------------------------------
 
 NEG_INF = -1e30
@@ -124,13 +128,29 @@ def _gqa_expand(q, n_kv):
 
 def flash_attention(q, k, v, *, causal=True, window=0, q_offset=0,
                     kv_block=512, scale=None):
-    """Chunked online-softmax attention.
+    """Attention of q over (k, v): on TPU the splash kernel
+    (`kernels/ops.attention`) where the call's own shapes, dtypes and
+    precision allow it (`ops.attention_fits`), else the jnp KV-block scan
+    (`_scan_attention`). Each runs under its `obs` scope.
 
     q: (B, Tq, H, hd); k,v: (B, Tk, KV, hd). q_offset: absolute position of
     q[0] relative to k[0] (for cached decode / chunked prefill).
     window: 0 = full; >0 = attend only to keys within `window` positions.
     scale: the scores' factor (None: hd^-1/2).
     """
+    if attn_seq_shard_axes() is None and ops.attention_fits(
+            q, k, v, window=window, q_offset=q_offset):
+        with jax.named_scope(obs.ATTN_KERNEL):
+            return ops.attention(q, k, v, causal=causal, scale=scale)
+    with jax.named_scope(obs.ATTN_JNP):
+        return _scan_attention(q, k, v, causal=causal, window=window,
+                               q_offset=q_offset, kv_block=kv_block,
+                               scale=scale)
+
+
+def _scan_attention(q, k, v, *, causal, window, q_offset, kv_block, scale):
+    """Chunked online-softmax attention: a scan over KV blocks of
+    `kv_block` keys, every block computed."""
     kv_block = attn_block_override(kv_block)
     if gqa_repeat_mode():
         # §Perf: keep attention tensors at full H heads — the 5D

@@ -9,6 +9,11 @@ Normal execution keeps rolled loops.
 
 REPRO_ATTN_BLOCK / REPRO_GLA_CHUNK let the dry-run coarsen the inner tile
 sizes to bound the unrolled HLO size (FLOPs are tile-size-invariant).
+
+REPRO_ATTN_BLOCK and REPRO_GQA_REPEAT act on the jnp attention scan alone
+(`layers._scan_attention`): a call that takes the TPU kernel
+(`kernels/ops.attention_fits`) picks its own blocks and reads grouped KV
+heads without a copy. REPRO_ATTN_SEQ_SHARD keeps every call on the scan.
 """
 from __future__ import annotations
 

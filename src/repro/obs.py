@@ -54,6 +54,13 @@ MOE_DISPATCH = "moe.dispatch"
 MOE_EXPERTS = "moe.experts"
 MOE_SHARED = "moe.shared"
 MOE_SCOPES = (MOE_ROUTE, MOE_DISPATCH, MOE_EXPERTS, MOE_SHARED)
+# The attention of `models/layers.flash_attention`, inside `step.task` as
+# well: the TPU kernel (`kernels/ops.attention`) where the call's shapes
+# allow it, else the jnp KV-block scan. The compiled step names the path
+# each call took.
+ATTN_KERNEL = "attn.kernel"
+ATTN_JNP = "attn.jnp"
+ATTN_SCOPES = (ATTN_KERNEL, ATTN_JNP)
 
 # Host spans, each written as SPAN_PREFIX + name.
 SPAN_PREFIX = "repro."

@@ -6,9 +6,9 @@ request latency. The server compiles ONE scoring program:
 
 * **vmapped members** — `jax.vmap(model.forward)` over the pool axis, so
   every member scores the batch inside a single jitted call. Transformer
-  members route through `kernels/flash_attention.py` exactly as in
-  training (Pallas on TPU, the `ref.py` path off-TPU) because the server
-  calls the model's own `forward`.
+  members run `models/layers.flash_attention` exactly as in training
+  (the splash kernel on TPU where the call's shapes allow it, the jnp
+  scan otherwise) because the server calls the model's own `forward`.
 * **a reduction head** — masked weighted mean of logits (default),
   weighted majority vote, or caller-supplied per-member weights /
   `weight_fn` (the hook ROADMAP item 4's density weighting feeds; weights

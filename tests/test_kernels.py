@@ -23,50 +23,54 @@ def _tol(dtype):
 
 
 # ---------------------------------------------------------------------------
-# flash attention
+# attention: the model's path (the jnp scan off-TPU) and the TPU kernel
+# (jax's splash attention, interpreted) against the dense reference
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("b,tq,tk,h,kv,hd", [
     (1, 32, 32, 4, 4, 16),     # MHA
     (2, 64, 64, 8, 2, 32),     # GQA 4x
-    (1, 48, 96, 4, 1, 64),     # MQA, tk > tq, non-multiple of block
+    (1, 48, 96, 4, 1, 64),     # MQA, tk > tq
     (2, 128, 128, 4, 4, 128),  # MXU-aligned
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("causal", [True, False])
 def test_flash_attention(b, tq, tk, h, kv, hd, dtype, causal):
+    from repro.models.layers import flash_attention
     ks = jax.random.split(jax.random.fold_in(KEY, tq * tk * h), 3)
     q = jax.random.normal(ks[0], (b, tq, h, hd), dtype)
     k = jax.random.normal(ks[1], (b, tk, kv, hd), dtype)
     v = jax.random.normal(ks[2], (b, tk, kv, hd), dtype)
-    out = ops.flash_attention(q, k, v, causal=causal, bq=32, bk=32)
+    out = flash_attention(q, k, v, causal=causal, kv_block=32)
     gold = ref.attention_ref(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(gold, np.float32), **_tol(dtype))
 
 
 def test_flash_attention_sliding_window():
+    from repro.models.layers import flash_attention
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (1, 64, 4, 32))
     k = jax.random.normal(ks[1], (1, 64, 4, 32))
     v = jax.random.normal(ks[2], (1, 64, 4, 32))
-    out = ops.flash_attention(q, k, v, causal=True, window=16, bq=16, bk=16)
+    out = flash_attention(q, k, v, causal=True, window=16, kv_block=16)
     gold = ref.attention_ref(q, k, v, causal=True, window=16)
     np.testing.assert_allclose(np.asarray(out), np.asarray(gold),
                                rtol=2e-5, atol=2e-5)
 
 
-def test_flash_matches_model_jnp_path():
-    """The model's chunked-jnp formulation and the Pallas kernel agree."""
-    from repro.models.layers import flash_attention as fa_jnp
+def test_attention_kernel_matches_reference():
+    """The TPU kernel, GQA 2x over one 128-token block, against the f32
+    reference on the same bf16 inputs."""
     ks = jax.random.split(KEY, 3)
-    q = jax.random.normal(ks[0], (2, 96, 8, 32))
-    k = jax.random.normal(ks[1], (2, 96, 4, 32))
-    v = jax.random.normal(ks[2], (2, 96, 4, 32))
-    a = fa_jnp(q, k, v, causal=True, kv_block=32)
-    b = ops.flash_attention(q, k, v, causal=True, bq=32, bk=32)
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                               rtol=2e-5, atol=2e-5)
+    q = jax.random.normal(ks[0], (2, 128, 4, 128), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (2, 128, 2, 128), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (2, 128, 2, 128), jnp.bfloat16)
+    out = ops.attention(q, k, v, causal=True)
+    gold = ref.attention_ref(q.astype(jnp.float32), k.astype(jnp.float32),
+                             v.astype(jnp.float32), causal=True)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(gold), **_tol(jnp.bfloat16))
 
 
 # ---------------------------------------------------------------------------

@@ -14,12 +14,18 @@ kernel may use, programs that do not fit the device — at no chip time.
 * the held experts' grouped GEMM (`ops.grouped_matmul`, the megablox
   kernel), the SwiGLU's gate, up and down and their backward, at
   DeepSeek-V2-Lite's widths and the deepseek-v2-lite.silo_train cell's
-  rows (8 x 2048 tokens x top-6, 8 experts held).
+  rows (8 x 2048 tokens x top-6, 8 experts held);
+* the training self-attention's splash kernels (`ops.attention`, through
+  `layers.flash_attention`), forward and backward, at both cells' shapes:
+  granite-8b (batch 1, 32 query heads over 8 KV heads, T 2048, head 128)
+  and deepseek-v2-lite (batch 4, 16 heads, T 2048, qk 192, v 128).
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
 imports this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -130,3 +136,34 @@ def test_grouped_matmul_swiglu_and_vjp_compile_at_deepseek_widths(
         x, w_in, w_in, w_out, sizes).compile()
     # forward 3, input gradients 3, weight gradients 3
     assert compiled.as_text().count("tpu_custom_call") >= 9
+
+
+# B, T, query heads, KV heads, qk dim, v dim, scale
+ATTN_CELLS = {"granite-8b": (1, 2048, 32, 8, 128, 128, None),
+              "deepseek-v2-lite": (4, 2048, 16, 16, 192, 128, 0.1147)}
+
+
+@pytest.mark.parametrize("cell", sorted(ATTN_CELLS))
+def test_attention_kernel_and_vjp_compile_at_the_cells_shapes(
+        one_chip, monkeypatch, cell):
+    from repro.kernels import ops
+    from repro.models import layers as L
+    monkeypatch.setattr(ops, "_use_pallas", lambda: True)
+    b, t, h, kv, hd, vd, scale = ATTN_CELLS[cell]
+    q = _spec((b, t, h, hd), jnp.bfloat16, one_chip)
+    k = _spec((b, t, kv, hd), jnp.bfloat16, one_chip)
+    v = _spec((b, t, kv, vd), jnp.bfloat16, one_chip)
+
+    def loss(q, k, v):
+        o = L.flash_attention(q, k, v, causal=True, scale=scale)
+        return (o.astype(jnp.float32) ** 2).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, k, v).compile()
+    # forward, dq and dkv, each printed on one line with its op's name,
+    # so that a line-wise reader of the program finds the kernel's scope
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) >= 3
+    assert all(re.search(r'op_name="[^"]*attn\.kernel', line)
+               for line in calls)
