@@ -1,5 +1,4 @@
-"""Regenerate the data-driven sections of EXPERIMENTS.md from
-experiments/dryrun/*.json, experiments/hillclimb/*.json and
+"""Regenerate the data-driven section of EXPERIMENTS.md from
 experiments/benchmarks/*.json. Idempotent: replaces the placeholder /
 previously generated blocks between the <!-- X --> markers.
 """
@@ -11,14 +10,6 @@ import os
 
 ROOT = os.path.join(os.path.dirname(__file__), "..")
 MD = os.path.join(ROOT, "EXPERIMENTS.md")
-
-
-def load(pattern):
-    out = []
-    for f in sorted(glob.glob(os.path.join(ROOT, pattern))):
-        with open(f) as fh:
-            out.append(json.load(fh))
-    return out
 
 
 def bench_section() -> str:
@@ -111,92 +102,6 @@ def bench_section() -> str:
     return "\n".join(lines)
 
 
-def dryrun_section() -> str:
-    recs = load("experiments/dryrun/*.json")
-    ok = [r for r in recs if r["status"] == "ok"]
-    sk = [r for r in recs if r["status"] == "skipped"]
-    lines = [f"**{len(ok)} / {len(recs)} combinations lower + compile** on "
-             "their assigned meshes (the remaining "
-             f"{len(sk)} are the documented long_500k carve-outs). "
-             "Compile wall-times 2–180 s on the CPU host. Per-combo "
-             "artifacts: `experiments/dryrun/*.json`.", "",
-             "Peak per-device memory (arguments + XLA temp) for the "
-             "heaviest shapes, baseline configuration:", "",
-             "| arch | shape | mesh | args GB | temp GB |", "|---|---|---|---|---|"]
-    heavy = sorted(ok, key=lambda r: -(r["memory"]["peak_bytes"] or 0))[:8]
-    for r in heavy:
-        m = r["memory"]
-        lines.append(f"| {r['arch']} | {r['shape']} | {r['mesh']} | "
-                     f"{m['argument_bytes']/1e9:.1f} | "
-                     f"{m['temp_bytes']/1e9:.1f} |")
-    lines += ["", "Baseline temp memory for train/prefill shapes exceeds "
-              "v5e HBM — driven down in §Perf (activation-sharding "
-              "constraints + microbatching); decode shapes fit as-is.", ""]
-    return "\n".join(lines)
-
-
-def roofline_section() -> str:
-    recs = [r for r in load("experiments/dryrun/*.json")
-            if r["status"] == "ok"]
-    lines = [
-        "Three terms in seconds/step/device (trip-corrected; memory term is "
-        "the pre-fusion upper bound — see methodology note 2). "
-        "`useful` = MODEL_FLOPS(6·N·D or 6·N_active·D; 2· for serving) / "
-        "corrected HLO FLOPs.", "",
-        "| arch | shape | mesh | compute s | memory s | collective s | "
-        "dominant | useful |", "|---|---|---|---|---|---|---|---|"]
-    for r in sorted(recs, key=lambda r: (r["shape"], r["arch"], r["mesh"])):
-        rl = r["roofline"]
-        u = r["useful_flops_ratio"]
-        lines.append(
-            f"| {r['arch']} | {r['shape']} | {r['mesh']} | "
-            f"{rl['compute_s']:.2e} | {rl['memory_s']:.2e} | "
-            f"{rl['collective_s']:.2e} | {r['dominant']} | "
-            f"{min(u, 99):.2f} |")
-    # per-row bottleneck notes
-    lines += ["", "Reading the table (baseline, before §Perf):", "",
-        "* **train_4k / prefill_32k are collective- or memory-bound across "
-        "the board** — root cause isolated in §Perf: GSPMD reshards "
-        "activations to batch-replicated/feature-sharded inside FFN layers "
-        "(multi-GB all-reduce + collective-permute per layer) unless "
-        "activations are pinned batch-sharded. What moves the dominant term "
-        "down: activation sharding constraints (then microbatching for the "
-        "memory term).",
-        "* **decode shapes are memory-bound** (as expected at batch ≤128: "
-        "one token reads all params + the KV cache) — the memory term is "
-        "the KV/latent-cache sweep; what would move it down is cache "
-        "quantization (int8) or MLA-style latent caches (deepseek row "
-        "already shows ~5× lower memory term than same-size dense).",
-        "* **SSM/hybrid long_500k rows** show bounded state advantage: "
-        "rwkv6/zamba2 at 500k context decode cost ≈ their 32k cost "
-        "(state-size-bound, not context-bound); llama3.2-1b's ring-buffer "
-        "sliding window caps its long-context decode at window size.",
-        "* `useful` ≪ 1 on baseline train rows is replicated-compute waste "
-        "(same GSPMD pathology), not remat: after the §Perf fix, "
-        "useful ≈ 0.76 (qwen2-72b) / 0.78 (qwen2-7b) with remat's ~1.33x "
-        "as the remaining gap.", ""]
-    # optimized re-sweep
-    opts = [r for r in load("experiments/hillclimb/*__opt*.json")
-            if r["status"] == "ok"]
-    if opts:
-        lines += ["### Optimized train_4k re-sweep (beyond-paper config: "
-                  "act-shard constraints + microbatch=4)", "",
-                  "| arch | mesh | compute s | memory s | collective s | "
-                  "dominant | temp GB |", "|---|---|---|---|---|---|---|"]
-        for r in sorted(opts, key=lambda r: (r["arch"], r["mesh"])):
-            rl = r["roofline"]
-            lines.append(
-                f"| {r['arch']} | {r['mesh']} | {rl['compute_s']:.2e} | "
-                f"{rl['memory_s']:.2e} | {rl['collective_s']:.2e} | "
-                f"{r['dominant']} | {r['memory']['temp_bytes']/1e9:.1f} |")
-        lines += ["", "(microbatch grad-accumulation loop is itself a scan "
-                  "counted once — compute/collective terms here are ~4x "
-                  "under-reported; compare temp GB and the ratio structure, "
-                  "or the per-pair §Perf ladders which hold microbatch "
-                  "fixed.)", ""]
-    return "\n".join(lines)
-
-
 def splice(md: str, marker: str, content: str) -> str:
     start = md.index(f"<!-- {marker} -->")
     end_tag = f"<!-- END {marker} -->"
@@ -213,8 +118,6 @@ def main():
     with open(MD) as f:
         md = f.read()
     md = splice(md, "BENCH_RESULTS", bench_section())
-    md = splice(md, "DRYRUN_SUMMARY", dryrun_section())
-    md = splice(md, "ROOFLINE_TABLE", roofline_section())
     with open(MD, "w") as f:
         f.write(md)
     print("EXPERIMENTS.md updated")

@@ -24,7 +24,7 @@ BENCHMARKS = ("table1_accuracy", "table2_fewshot", "table3_ablation",
               "table4_order", "fig5_comm_cost", "fig6_compute_matched",
               "fig9_distance_measures", "fig10_pool_heatmap", "table9_pfl",
               "scenario_grid", "local_phase", "local_phase_cnn",
-              "roofline_report", "serving", "fleet_throughput",
+              "serving", "fleet_throughput",
               "pool_memory")
 
 

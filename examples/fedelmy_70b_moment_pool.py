@@ -8,8 +8,6 @@ model, then prints the memory budgets for the assigned 72B config.
 
     PYTHONPATH=src python examples/fedelmy_70b_moment_pool.py
 """
-import dataclasses
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -17,7 +15,6 @@ import numpy as np
 from repro.api import get_pool_backend
 from repro.configs import FedConfig, get_arch
 from repro.core import pairwise_distance
-from repro.launch.steps import param_specs_for
 from repro.models import build_model
 
 
@@ -45,7 +42,7 @@ def main():
 
     # memory budget at the assigned 72B config
     big = get_arch("qwen2-72b")
-    shapes = param_specs_for(big)
+    shapes = jax.eval_shape(build_model(big).init, jax.random.PRNGKey(0))
     n_params = sum(int(np.prod(l.shape)) for l in jax.tree.leaves(shapes))
     bytes_per = 2  # bf16
     s = 5

@@ -7,9 +7,9 @@ domain-shifted clients.
 Four clients hold token streams from different Markov domains (synthetic
 domain shift). Each client trains a pool of S=2 models with the d1/d2
 objective; held-out perplexity of the traveling average is tracked after
-every client. This is the production path: the same train_step that the
-multi-pod dry-run lowers at qwen2-72b scale (launch/steps.py), on a small
-mesh.
+every client. This is the production path: the same `repro.api.launch`
+and local-phase programs that every architecture trains through, at a
+small size.
 """
 import argparse
 import dataclasses

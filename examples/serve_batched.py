@@ -1,6 +1,6 @@
-"""Serving example: batched autoregressive decoding with the serve_step the
-dry-run lowers — prefill a batch of prompts, then decode tokens with the
-KV/SSM cache, for three different architecture families.
+"""Serving example: batched autoregressive decoding with the models'
+prefill and decode — prefill a batch of prompts, then decode tokens with
+the KV/SSM cache, for three different architecture families.
 
     PYTHONPATH=src python examples/serve_batched.py [--new-tokens 16]
 """

@@ -1,6 +1,6 @@
 """Config registry: ``--arch <id>`` maps into ARCHS."""
-from repro.configs.base import (ArchConfig, FedConfig, INPUT_SHAPES, MLAConfig,
-                                MoEConfig, ShapeConfig, SSMConfig, YarnConfig)
+from repro.configs.base import (ArchConfig, FedConfig, MLAConfig, MoEConfig,
+                                SSMConfig, YarnConfig)
 from repro.configs import (chameleon_34b, deepseek_v2_lite_16b, granite_8b,
                            llama3_2_1b, paper_cnn, qwen2_7b, qwen2_72b,
                            qwen3_moe_235b_a22b, rwkv6_7b, seamless_m4t_medium,
@@ -28,5 +28,5 @@ def get_arch(name: str) -> ArchConfig:
     return ARCHS[name]
 
 
-__all__ = ["ARCHS", "get_arch", "ArchConfig", "FedConfig", "INPUT_SHAPES",
-           "MLAConfig", "MoEConfig", "ShapeConfig", "SSMConfig", "YarnConfig"]
+__all__ = ["ARCHS", "get_arch", "ArchConfig", "FedConfig", "MLAConfig",
+           "MoEConfig", "SSMConfig", "YarnConfig"]

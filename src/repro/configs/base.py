@@ -80,7 +80,8 @@ class ArchConfig:
     shared_attn_every: int = 0
     # enc-dec
     n_encoder_layers: int = 0
-    # sliding-window attention (0 = full attention). Enables long_500k decode.
+    # sliding-window attention (0 = full attention); decode keeps a ring
+    # buffer of the window.
     sliding_window: int = 0
     # Leading dense layers of a MoE decoder (DeepSeek's
     # `first_k_dense_replace`), with their SwiGLU width (0: `d_ff`).
@@ -92,7 +93,7 @@ class ArchConfig:
     # `moe.n_experts` (0: all of them); the device computes its own experts'
     # part of the layer (one chip's share of expert parallelism).
     experts_held: int = 0
-    # dtype for params in the dry-run / production config
+    # dtype of the params
     param_dtype: str = "bfloat16"
     # activation checkpointing of each scanned layer (False: none, so every
     # scan activation is stored, ~18 TB/device for qwen2-72b train_4k).
@@ -100,7 +101,7 @@ class ArchConfig:
     # backward where they fit in a quarter of the device's memory
     # (`transformer.KEEP_PROJ_SHARE`), so the backward recomputes the norms,
     # RoPE, SwiGLU and attention but no projection GEMM; otherwise, and
-    # where the device reports no memory (CPU, the dry-run), it recomputes
+    # where the device reports no memory (CPU), it recomputes
     # the whole layer, ~1.33x the FLOPs. Other families always recompute.
     remat: bool = True
     source: str = ""              # citation
@@ -120,13 +121,6 @@ class ArchConfig:
     @property
     def is_attention_free(self) -> bool:
         return self.family == "ssm"
-
-    @property
-    def supports_long_decode(self) -> bool:
-        """True if serve_step at 500k context is sub-quadratic / bounded-state."""
-        if self.family in ("ssm", "hybrid"):
-            return True
-        return self.sliding_window > 0
 
     def reduced(self) -> "ArchConfig":
         """A smoke-test-sized variant of the same family (<=2 layers, d<=512)."""
@@ -174,22 +168,6 @@ class ArchConfig:
         if self.sliding_window:
             kw["sliding_window"] = 64
         return ArchConfig(**kw)
-
-
-@dataclasses.dataclass(frozen=True)
-class ShapeConfig:
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str                     # "train" | "prefill" | "decode"
-
-
-INPUT_SHAPES = {
-    "train_4k": ShapeConfig("train_4k", 4096, 256, "train"),
-    "prefill_32k": ShapeConfig("prefill_32k", 32768, 32, "prefill"),
-    "decode_32k": ShapeConfig("decode_32k", 32768, 128, "decode"),
-    "long_500k": ShapeConfig("long_500k", 524288, 1, "decode"),
-}
 
 
 # Valid FedConfig string knobs. Mirrored (not imported) from repro.core

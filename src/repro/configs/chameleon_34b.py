@@ -2,7 +2,7 @@
 
 VQ image tokens are ordinary vocabulary entries (vocab 65536 includes the
 8192 image codes), so the backbone is a plain dense decoder; the VQ-VAE
-tokenizer is the stubbed modality frontend (input_specs feeds token ids).
+tokenizer is the stubbed modality frontend (the batch carries token ids).
 """
 from repro.configs.base import ArchConfig
 

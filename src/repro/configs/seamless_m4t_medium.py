@@ -1,7 +1,7 @@
 """SeamlessM4T-medium [arXiv:2308.11596] — enc-dec multimodal (audio).
 
 The speech frontend (mel filterbank + conv feature extractor) is the stubbed
-modality frontend: input_specs() feeds precomputed frame embeddings of shape
+modality frontend: the batch carries precomputed frame embeddings of shape
 (B, T_src, d_model). We build the 12L transformer encoder + 12L decoder with
 cross-attention over the 256206-entry text vocabulary.
 """

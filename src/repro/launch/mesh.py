@@ -1,12 +1,7 @@
-"""Production mesh construction.
+"""Meshes over the local devices.
 
-A function, not a module-level constant, so importing this module never
-touches jax device state (jax locks the device count on first use — the
-dry-run must set XLA_FLAGS before any jax call).
-
-Target hardware: TPU v5e pod slices.
-  single-pod : (data=16, model=16)            = 256 chips
-  multi-pod  : (pod=2, data=16, model=16)     = 512 chips
+Functions, not module-level constants, so importing this module never
+touches jax device state (jax locks the device count on first use).
 """
 from __future__ import annotations
 
@@ -20,12 +15,6 @@ def _mesh(shape, axes):
     not enter the array types (Explicit axes, jax's default, make eager
     indexing of a sharded run axis an error)."""
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _mesh(shape, axes)
 
 
 def make_local_mesh():
@@ -58,9 +47,3 @@ def make_cohort_mesh(flat: int = 0):
         while n > 1 and flat % n:
             n -= 1
     return _mesh((n, 1), ("data", "model"))
-
-
-# TPU v5e roofline constants (per chip) — used by repro.analysis.roofline
-PEAK_FLOPS_BF16 = 197e12        # FLOP/s
-HBM_BW = 819e9                  # B/s
-ICI_BW = 50e9                   # B/s per link

@@ -4,9 +4,8 @@
       --clients 4 --pool 3 --e-local 20 [--method fedseq|fedelmy|...]
       [--handoff-dir /tmp/handoff]   # serialize client→client transfers
 
-On a real TPU fleet each client's local training runs under the production
-mesh (launch/mesh.py); here the local mesh is whatever devices exist. The
---handoff-dir flag exercises the checkpoint-based transfer path (the
+Each client's local training runs on the local devices (launch/mesh.py).
+The --handoff-dir flag exercises the checkpoint-based transfer path (the
 actual wire format between pods/sites); omitted, handoffs stay in memory.
 """
 from __future__ import annotations

@@ -21,9 +21,6 @@ from jax.ad_checkpoint import checkpoint_name
 
 from repro import obs
 from repro.kernels import ops
-from repro.models.scan_util import (attn_block_override, attn_seq_shard_axes,
-                                    constrain_act, gqa_repeat_mode,
-                                    inner_scan)
 
 ACC = jnp.float32
 
@@ -113,8 +110,8 @@ def apply_rope(x, positions, theta, freqs=None, amp=1.0):
 # On TPU, self-attention of a whole bf16 sequence runs jax's splash
 # attention kernels, forward and backward, through `kernels/ops.attention`.
 # The jnp scan below is the path for every other call (cached decode,
-# cross-attention, short windows, f32 models, sharded sequences), the CPU
-# and dry-run path, and the kernel's oracle.
+# cross-attention, short windows, f32 models), the CPU path, and the
+# kernel's oracle.
 # ---------------------------------------------------------------------------
 
 NEG_INF = -1e30
@@ -128,18 +125,18 @@ def _gqa_expand(q, n_kv):
 
 def flash_attention(q, k, v, *, causal=True, window=0, q_offset=0,
                     kv_block=512, scale=None):
-    """Attention of q over (k, v): on TPU the splash kernel
-    (`kernels/ops.attention`) where the call's own shapes, dtypes and
-    precision allow it (`ops.attention_fits`), else the jnp KV-block scan
-    (`_scan_attention`). Each runs under its `obs` scope.
+    """Attention of q over (k, v): the splash kernel
+    (`kernels/ops.attention`) where the call's own shapes, dtypes,
+    precision and platform allow it (`ops.attention_fits`), else the jnp
+    scan over KV blocks of `kv_block` keys (`_scan_attention`). Each runs
+    under its `obs` scope.
 
     q: (B, Tq, H, hd); k,v: (B, Tk, KV, hd). q_offset: absolute position of
     q[0] relative to k[0] (for cached decode / chunked prefill).
     window: 0 = full; >0 = attend only to keys within `window` positions.
     scale: the scores' factor (None: hd^-1/2).
     """
-    if attn_seq_shard_axes() is None and ops.attention_fits(
-            q, k, v, window=window, q_offset=q_offset):
+    if ops.attention_fits(q, k, v, window=window, q_offset=q_offset):
         with jax.named_scope(obs.ATTN_KERNEL):
             return ops.attention(q, k, v, causal=causal, scale=scale)
     with jax.named_scope(obs.ATTN_JNP):
@@ -151,24 +148,6 @@ def flash_attention(q, k, v, *, causal=True, window=0, q_offset=0,
 def _scan_attention(q, k, v, *, causal, window, q_offset, kv_block, scale):
     """Chunked online-softmax attention: a scan over KV blocks of
     `kv_block` keys, every block computed."""
-    kv_block = attn_block_override(kv_block)
-    if gqa_repeat_mode():
-        # §Perf: keep attention tensors at full H heads — the 5D
-        # (B,T,KV,G,hd) grouping makes the KV axis (4–8) unshardable over a
-        # 16-way model axis and GSPMD falls back to replicate+all-reduce.
-        # jnp.repeat keeps every score/out tensor sharded per head.
-        g_rep = q.shape[2] // k.shape[2]
-        if g_rep > 1:
-            k = jnp.repeat(k, g_rep, axis=2)
-            v = jnp.repeat(v, g_rep, axis=2)
-    seq_shard = attn_seq_shard_axes()
-    if seq_shard is not None:
-        from jax.sharding import PartitionSpec as _P
-        batch_ax, seq_ax = seq_shard
-        ba = batch_ax if len(batch_ax) > 1 else batch_ax[0]
-        q = jax.lax.with_sharding_constraint(q, _P(ba, seq_ax, None, None))
-        k = jax.lax.with_sharding_constraint(k, _P(ba, None, None, None))
-        v = jax.lax.with_sharding_constraint(v, _P(ba, None, None, None))
     b, tq, h, hd = q.shape
     tk, n_kv = k.shape[1], k.shape[2]
     vd = v.shape[-1]
@@ -209,7 +188,7 @@ def _scan_attention(q, k, v, *, causal, window, q_offset, kv_block, scale):
     init = (jnp.full((b, tq, n_kv, g), NEG_INF, ACC),
             jnp.zeros((b, tq, n_kv, g), ACC),
             jnp.zeros((b, tq, n_kv, g, vd), ACC))
-    (m, l, acc), _ = inner_scan(
+    (m, l, acc), _ = jax.lax.scan(
         step, init, (kb.swapaxes(0, 1), vb.swapaxes(0, 1),
                      jnp.arange(n_blocks)))
     out = acc / jnp.maximum(l, 1e-30)[..., None]
@@ -221,15 +200,9 @@ def decode_attention(q, k_cache, v_cache, cache_pos, pos, *, window=0):
 
     q: (B, 1, H, hd); caches: (B, W, KV, hd); cache_pos: (B, W) absolute
     positions of cached entries (-1 = empty); pos: (B,) current position.
-    Plain (non-chunked) formulation: scores are (B,H,W) which is small for a
-    single query, and GSPMD turns the W-axis reductions into the
-    flash-decoding-style partial-softmax + all-reduce when W is sharded.
+    Plain (non-chunked) formulation: the scores are (B,H,W), small for a
+    single query. Grouped KV heads are read in place, without a copy.
     """
-    if gqa_repeat_mode():
-        g_rep = q.shape[2] // k_cache.shape[2]
-        if g_rep > 1:
-            k_cache = jnp.repeat(k_cache, g_rep, axis=2)
-            v_cache = jnp.repeat(v_cache, g_rep, axis=2)
     b, _, h, hd = q.shape
     n_kv = k_cache.shape[2]
     g = h // n_kv
@@ -265,7 +238,6 @@ def attn_init(key, cfg, dtype):
 
 
 def _proj(x, w, b=None):
-    x = constrain_act(x)
     y = jnp.einsum("btd,df->btf", x, w, preferred_element_type=ACC)
     if b is not None:
         y = y + b.astype(ACC)
@@ -395,12 +367,10 @@ def mlp_init(key, d, d_ff, dtype):
 
 
 def mlp(p, x):
-    x = constrain_act(x)
     g = checkpoint_name(jnp.einsum("btd,df->btf", x, p["w_gate"],
                                    preferred_element_type=ACC), PROJ)
     u = checkpoint_name(jnp.einsum("btd,df->btf", x, p["w_up"],
                                    preferred_element_type=ACC), PROJ)
-    y = constrain_act(jax.nn.silu(g) * u, hidden=True)
-    out = jnp.einsum("btf,fd->btd", y.astype(x.dtype), p["w_down"],
-                     preferred_element_type=ACC).astype(x.dtype)
-    return constrain_act(out)
+    y = jax.nn.silu(g) * u
+    return jnp.einsum("btf,fd->btd", y.astype(x.dtype), p["w_down"],
+                      preferred_element_type=ACC).astype(x.dtype)

@@ -12,7 +12,7 @@ quadratic intra-chunk in pairwise log-decay-difference form — every exponent
 is ≤ 0, so it is overflow-safe without FLA-style sub-chunking). Decode is the
 plain one-step recurrence. The Pallas kernel `repro.kernels.chunk_scan` is
 the TPU-target implementation of the intra-chunk block; this module is the
-lowering path for CPU dry-runs and the oracle's home.
+path every model lowers and the oracle's home.
 """
 from __future__ import annotations
 
@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.layers import ACC, _he, rms_norm, rms_norm_init
-from repro.models.scan_util import gla_chunk_override, inner_scan
 
 
 # ---------------------------------------------------------------------------
@@ -42,7 +41,7 @@ def gla_chunked(q, k, v, log_decay, *, chunk: int, bonus=None,
     b, t, h, kd = q.shape
     vd = v.shape[-1]
     per_channel = log_decay.ndim == 4
-    chunk = min(gla_chunk_override(chunk), t)
+    chunk = min(chunk, t)
     pad = (-t) % chunk
     if pad:
         # zero-pad: k=v=0 contributes nothing; log_decay=0 leaves the state
@@ -106,7 +105,7 @@ def gla_chunked(q, k, v, log_decay, *, chunk: int, bonus=None,
             "blhk,blhv->bhkv", k_eff, vx)
         return S_new, y
 
-    S, ys = inner_scan(chunk_step, initial_state, (qc, kc, vc, ldc))
+    S, ys = jax.lax.scan(chunk_step, initial_state, (qc, kc, vc, ldc))
     y = ys.swapaxes(0, 1).reshape(b, t, h, vd)
     if pad:
         y = y[:, :t - pad]

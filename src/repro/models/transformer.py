@@ -3,13 +3,14 @@ for every assigned architecture family from an ArchConfig.
 
 Structural choices (see DESIGN.md):
 * Per-layer parameters are stacked on a leading L axis and consumed with
-  ``jax.lax.scan`` — keeps HLO size O(1) in depth (essential for the 80–94
-  layer configs on a CPU-hosted 512-device dry-run).
+  ``jax.lax.scan`` — keeps HLO size O(1) in depth (the 80–94 layer
+  configs compile in the time of one layer).
 * The LM loss is computed in vocab-chunks (scan over the T axis) so the
   (B, T, V) logits tensor is never materialized — critical for the 256206-
   vocab seamless-m4t config.
 * Decode uses ring-buffer KV caches when a sliding window is configured,
-  making long_500k bounded-memory for the dense sliding-window variant.
+  making long-context decode bounded-memory for the dense sliding-window
+  variant.
 """
 from __future__ import annotations
 
@@ -28,27 +29,9 @@ from repro.models import layers as L
 from repro.models import moe as MOE
 from repro.models import ssm as SSM
 from repro.models.layers import ACC
-from repro.models.scan_util import inner_scan
 
 PyTree = Any
 LOSS_CHUNK = 512
-
-# Dry-run accuracy knob: XLA's cost analysis counts a while-loop body ONCE
-# regardless of trip count, which would undercount scanned layers by ~L.
-# REPRO_SCAN_UNROLL=0 fully unrolls the layer scans so cost_analysis and the
-# HLO collective parse are exact (launch/dryrun.py sets it; normal training
-# keeps the rolled loop for compile-time sanity).
-import os as _os
-
-def _scan(f, init, xs, length=None):
-    unroll_env = _os.environ.get("REPRO_SCAN_UNROLL", "")
-    kw = {}
-    if unroll_env == "full":
-        kw["unroll"] = True
-    elif unroll_env.isdigit() and int(unroll_env) > 1:
-        kw["unroll"] = int(unroll_env)
-    return jax.lax.scan(f, init, xs, length=length, **kw)
-
 
 class Model(NamedTuple):
     cfg: ArchConfig
@@ -122,7 +105,7 @@ def chunked_xent(params, cfg, h, labels):
         gold = jnp.take_along_axis(logits, lx[..., None], axis=-1)[..., 0]
         return tot + jnp.sum(lse - gold), None
 
-    tot, _ = inner_scan(step, jnp.zeros((), ACC), (hc, lc))
+    tot, _ = jax.lax.scan(step, jnp.zeros((), ACC), (hc, lc))
     return tot / (b * n * chunk)
 
 
@@ -305,29 +288,11 @@ def build_decoder_only(cfg: ArchConfig) -> Model:
         if cfg.remat:
             layer = decoder_remat(cfg, b, t, x.dtype)(layer)
 
-        # §Perf: REPRO_REMAT_SEGMENTS=k — hierarchical (√L-style) remat.
-        # Plain remat-in-scan still stashes every layer's input carry
-        # (L × B·T·D); segmenting checkpoints only k outer carries and
-        # recomputes each segment (inner layers re-checkpointed) — carry
-        # stash drops L/k× for one extra forward.
-        n_seg = int(_os.environ.get("REPRO_REMAT_SEGMENTS", "1"))
         carry, counts = (x, jnp.zeros((), ACC)), []
         for name, n, _ in groups:
-            if n_seg > 1 and n % n_seg == 0 and not collect:
-                per = n // n_seg
-                seg_params = jax.tree.map(
-                    lambda a: a.reshape(n_seg, per, *a.shape[1:]),
-                    params[name])
-
-                def segment(carry, sp):
-                    out, _ = _scan(layer, carry, sp)
-                    return out, None
-
-                carry, _ = _scan(jax.checkpoint(segment), carry, seg_params)
-            else:
-                carry, sizes = _scan(layer, carry, params[name])
-                if sizes is not None:
-                    counts.append(sizes)
+            carry, sizes = jax.lax.scan(layer, carry, params[name])
+            if sizes is not None:
+                counts.append(sizes)
         x, aux = carry
         if collect:
             return x, aux, jnp.concatenate(counts)
@@ -399,7 +364,7 @@ def build_decoder_only(cfg: ArchConfig) -> Model:
 
         carry, per_group = (x, jnp.zeros((), ACC)), []
         for name, _, _ in groups:
-            carry, kvs = _scan(layer, carry, params[name])
+            carry, kvs = jax.lax.scan(layer, carry, params[name])
             per_group.append(kvs)
         x = carry[0]
         kvs = _join(per_group)
@@ -466,7 +431,7 @@ def build_decoder_only(cfg: ArchConfig) -> Model:
         for name, n, _ in groups:
             part = (lambda c: c) if len(groups) == 1 else (
                 lambda c: c[start:start + n])
-            carry, new = _scan(layer, carry, (params[name],) + tuple(
+            carry, new = jax.lax.scan(layer, carry, (params[name],) + tuple(
                 part(cache[c]) for c in names))
             per_group.append(new)
             start += n
@@ -573,7 +538,7 @@ def build_hybrid(cfg: ArchConfig) -> Model:
             seg_params = jax.tree.map(
                 lambda a: a[si * seg_len:(si + 1) * seg_len],
                 params["layers"])
-            (x,), _ = _scan(layer, (x,), seg_params)
+            (x,), _ = jax.lax.scan(layer, (x,), seg_params)
             if every:
                 x = _shared_block(sp, x, positions)
         return x
@@ -630,7 +595,7 @@ def build_hybrid(cfg: ArchConfig) -> Model:
         for si in range(n_seg):
             seg_params = jax.tree.map(
                 lambda a: a[si * seg_len:(si + 1) * seg_len], params["layers"])
-            (x,), (sts, ncvs) = _scan(seg_layer, (x,), seg_params)
+            (x,), (sts, ncvs) = jax.lax.scan(seg_layer, (x,), seg_params)
             ssm_states.append(sts)
             conv_states.append(ncvs)
             if every:
@@ -694,7 +659,7 @@ def build_hybrid(cfg: ArchConfig) -> Model:
         for si in range(n_seg):
             sl = slice(si * seg_len, (si + 1) * seg_len)
             seg = jax.tree.map(lambda a: a[sl], params["layers"])
-            (x,), (sts, cvs) = _scan(
+            (x,), (sts, cvs) = jax.lax.scan(
                 layer, (x,), (seg, cache["ssm"][sl], cache["conv"][sl]))
             sts_all.append(sts)
             cvs_all.append(cvs)
@@ -742,7 +707,7 @@ def build_rwkv(cfg: ArchConfig) -> Model:
 
         if cfg.remat:
             layer = jax.checkpoint(layer)
-        (x,), _ = _scan(layer, (x,), params["layers"])
+        (x,), _ = jax.lax.scan(layer, (x,), params["layers"])
         return x
 
     def forward(params, batch):
@@ -781,7 +746,7 @@ def build_rwkv(cfg: ArchConfig) -> Model:
             x = x + L.mlp(lp["ffn"], L.rms_norm(lp["ln2"], x, cfg.norm_eps))
             return (x,), (st, x_last)
 
-        (x,), (sts, xls) = _scan(layer, (x,), params["layers"])
+        (x,), (sts, xls) = jax.lax.scan(layer, (x,), params["layers"])
         return lm_logits(params, cfg, x[:, -1:]), \
             {"state": sts, "x_prev": xls}
 
@@ -798,7 +763,7 @@ def build_rwkv(cfg: ArchConfig) -> Model:
             x = x + L.mlp(lp["ffn"], L.rms_norm(lp["ln2"], x, cfg.norm_eps))
             return (x,), (st, xp)
 
-        (x,), (sts, xps) = _scan(
+        (x,), (sts, xps) = jax.lax.scan(
             layer, (x,), (params["layers"], cache["state"], cache["x_prev"]))
         return lm_logits(params, cfg, x), {"state": sts, "x_prev": xps}
 
@@ -851,7 +816,7 @@ def build_encdec(cfg: ArchConfig) -> Model:
 
         if cfg.remat:
             layer = jax.checkpoint(layer)
-        (x,), _ = _scan(layer, (src.astype(dtype),), params["encoder"])
+        (x,), _ = jax.lax.scan(layer, (src.astype(dtype),), params["encoder"])
         return x
 
     def _cross_kv(lp, enc_out):
@@ -878,7 +843,7 @@ def build_encdec(cfg: ArchConfig) -> Model:
 
         if cfg.remat:
             layer = jax.checkpoint(layer)
-        (x,), _ = _scan(layer, (x,), params["decoder"])
+        (x,), _ = jax.lax.scan(layer, (x,), params["decoder"])
         return x
 
     def forward(params, batch):
@@ -923,7 +888,7 @@ def build_encdec(cfg: ArchConfig) -> Model:
             x = x + L.mlp(lp["ffn"], L.rms_norm(lp["ln2"], x, cfg.norm_eps))
             return (x,), (k, v, ck, cv)
 
-        (x,), (ks, vs, cks, cvs) = _scan(layer, (x,), params["decoder"])
+        (x,), (ks, vs, cks, cvs) = jax.lax.scan(layer, (x,), params["decoder"])
         return lm_logits(params, cfg, x[:, -1:]), \
             {"k": ks, "v": vs, "cross_k": cks, "cross_v": cvs}
 
@@ -957,7 +922,7 @@ def build_encdec(cfg: ArchConfig) -> Model:
             x = x + L.mlp(lp["ffn"], L.rms_norm(lp["ln2"], x, cfg.norm_eps))
             return (x,), (k_l, v_l)
 
-        (x,), (ks, vs) = _scan(
+        (x,), (ks, vs) = jax.lax.scan(
             layer, (x,), (params["decoder"], cache["k"], cache["v"],
                           cache["cross_k"], cache["cross_v"]))
         logits = lm_logits(params, cfg, x)

