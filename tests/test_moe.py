@@ -9,6 +9,12 @@ small size in f32.
   uncut reference's layer.
 * Dropless: the layer equals a dense all-expert einsum weighted by the
   gates, also under a router that sends every token to the same experts.
+* The held path's row ladder (`held_rungs`, `held_capacity`), and the
+  compacted layer against the dense einsum, output and gradient, at each
+  rung: a random router, one biased past the first rung, and every token
+  on held experts; one rung and no `switch` where every expert is held.
+* The gradient of a checkpointed layer, lowered: no array of N*k rows
+  outside the last rung's branch.
 * The gates with and without `norm_topk_prob`.
 * YaRN's frequencies, mscale and softmax scale against the published
   formula at DeepSeek-V2-Lite's values.
@@ -19,7 +25,9 @@ small size in f32.
   against its jnp twin.
 """
 import dataclasses
+import functools
 import math
+import re
 import os
 import sys
 
@@ -120,12 +128,12 @@ def test_shares_add_up_to_the_uncut_layer():
 
 
 def _dense_moe(p, cfg, x):
-    """Every expert on every token, weighted by the gate of the tokens
+    """Every held expert on every token, weighted by the gate of the tokens
     that chose it; the shared experts added."""
     gates, idx, _ = MOE.route(p["router"], cfg, x)
     w = jnp.sum(jax.nn.one_hot(idx, cfg.moe.n_experts) * gates[..., None],
-                1)                                              # (N, E)
-    xf = x.reshape(-1, D)
+                1)[:, :p["w_gate"].shape[0]]                    # (N, held)
+    xf = x.reshape(-1, x.shape[-1])
     h = jax.nn.silu(jnp.einsum("nd,edf->enf", xf, p["w_gate"])) * \
         jnp.einsum("nd,edf->enf", xf, p["w_up"])
     y = jnp.einsum("enf,efd,ne->nd", h, p["w_down"], w)
@@ -146,6 +154,148 @@ def test_dropless_equals_dense_all_expert_einsum(skew):
     assert int(sizes.sum()) == x.shape[0] * x.shape[1] * K
     np.testing.assert_allclose(np.asarray(y), np.asarray(_dense_moe(
         p, cfg, x)), rtol=1e-5, atol=1e-5)
+
+
+def test_held_rungs_ladder():
+    # the deepseek-v2-lite.silo_train cell: 8192 tokens, top-6, 8 of 64 held
+    assert MOE.held_rungs(8192, 6, 8, 64) == (7680, 19456, 49152)
+    for n, k, held, e in [(8192, 6, 8, 64), (2048, 2, 2, 8),
+                          (1536, 2, 4, 16), (4096, 8, 4, 128)]:
+        rungs = MOE.held_rungs(n, k, held, e)
+        assert 1 < len(rungs) <= 3 and rungs[-1] == n * min(k, held)
+        assert all(c % ops.GMM_TM == 0 for c in rungs)
+        assert all(a < b for a, b in zip(rungs, rungs[1:]))
+        assert rungs[0] >= 1.25 * n * k * held / e
+        cap = functools.partial(MOE.held_capacity, n, k, held, e)
+        assert cap(0) == cap(rungs[0]) == rungs[0]
+        assert cap(rungs[0] + 1) == rungs[1] and cap(rungs[-1]) == rungs[-1]
+        with pytest.raises(ValueError):
+            cap(rungs[-1] + 1)
+    for n, k, held, e in [(8192, 6, 64, 64), (48, 3, 8, 8), (48, 3, 4, 8)]:
+        assert MOE.held_rungs(n, k, held, e) == (n * min(k, held),)
+
+
+# The held path at more than one rung: 2048 tokens, top-2 of 8 experts, 2
+# held; rungs (1536, 2560, 4096).
+LADDER = dict(n_experts=8, top_k=2, held=2)
+
+
+def _steered(p, x, pulls):
+    """Every token's logits raised by `pulls[e]` for expert e, through a
+    constant first feature."""
+    x = x.at[..., 0].set(10.0)
+    router = p["router"].at[0].set(0.0)
+    for e, pull in enumerate(pulls):
+        router = router.at[0, e].set(pull)
+    return {**p, "router": router}, x
+
+
+@pytest.mark.parametrize("rung,pulls", [(0, ()), (1, (1.5,)),
+                                        (2, (2.0, 1.5)), (0, None)],
+                         ids=["random", "middle", "last", "all_held"])
+def test_compacted_layer_equals_dense_einsum_with_grads(rung, pulls):
+    all_held = pulls is None
+    cfg = _moe_cfg(**(dict(LADDER, held=0) if all_held else LADDER))
+    held = cfg.resolved_experts_held
+    p = _share(_layer_params(jax.random.PRNGKey(12)), 0, held)
+    x = jax.random.normal(jax.random.PRNGKey(13), (2, 1024, D))
+    p, x = _steered(p, x, pulls or ())
+    n, k = 2048, cfg.moe.top_k
+    rungs = MOE.held_rungs(n, k, held, cfg.moe.n_experts)
+    assert rungs == ((4096,) if all_held else (1536, 2560, 4096))
+    _, _, sizes = MOE.moe_ffn(p, cfg, x)
+    h = int(sizes.sum())
+    assert MOE.held_capacity(n, k, held, cfg.moe.n_experts, h) == rungs[rung]
+    if rung == 2:                   # dropless at the last rung
+        assert h == n * min(k, held)
+    ct = jax.random.normal(jax.random.PRNGKey(14), x.shape)
+    panels = ("w_gate", "w_up", "w_down")
+
+    def loss(layer):
+        def f(x, *w):
+            y = layer({**p, **dict(zip(panels, w))}, cfg, x)
+            return jnp.sum((y[0] if isinstance(y, tuple) else y) * ct)
+        return jax.grad(f, argnums=(0, 1, 2, 3))
+
+    args = (x,) + tuple(p[n] for n in panels)
+    y = MOE.moe_ffn(p, cfg, x)[0]
+    np.testing.assert_allclose(np.asarray(y), np.asarray(_dense_moe(
+        p, cfg, x)), rtol=1e-5, atol=1e-5)
+    for a, b in zip(loss(MOE.moe_ffn)(*args), loss(_dense_moe)(*args)):
+        scale = float(jnp.max(jnp.abs(b)))
+        assert float(jnp.max(jnp.abs(a - b))) <= 1e-5 * scale
+    jaxpr = str(jax.make_jaxpr(loss(MOE.moe_ffn))(*args))
+    assert ("cond[" in jaxpr) == (not all_held)
+
+
+def _outside_last_branch(text, rows):
+    """Lines of lowered StableHLO outside the last region of every
+    `stablehlo.case` (and outside private functions called only from
+    there) that hold a floating-point array of `rows` rows and rank two
+    or more: the buffers of the data, not the routing's int indices."""
+    big = re.compile(rf"tensor<{rows}(x\d+)+x(f|bf)\d+>")
+    cases, stack, lines = [], [], []     # stack: [case, region] or None
+    func = None
+    for line in text.splitlines():
+        s = line.strip()
+        m = re.match(r"func\.func (?:public |private )?@([\w.$-]+)", s)
+        if m:
+            func = m.group(1)
+        if s == "}, {" and stack and stack[-1] is not None:
+            stack[-1][1] += 1
+            cases[stack[-1][0]] += 1
+            continue
+        net = s.count("{") - s.count("}")
+        if net < 0:                     # a case's results lie outside it
+            del stack[net:]
+        lines.append((func, line, [f and tuple(f) for f in stack]))
+        if net > 0:
+            opens = [None] * net
+            if '"stablehlo.case"' in s:
+                cases.append(1)
+                opens[-1] = [len(cases) - 1, 0]
+            stack += opens
+
+    def in_last(frames):
+        return any(f is not None and f[1] == cases[f[0]] - 1
+                   for f in frames)
+
+    calls = {}                           # callee -> [(caller, in last)]
+    for func, line, frames in lines:
+        for callee in re.findall(r"call @([\w.$-]+)", line):
+            calls.setdefault(callee, []).append((func, in_last(frames)))
+    inside = set()
+    while True:
+        more = {f for f, sites in calls.items() if f not in inside and all(
+            last or caller in inside for caller, last in sites)}
+        if not more:
+            break
+        inside |= more
+    return [line.strip() for func, line, frames in lines
+            if big.search(line) and not in_last(frames)
+            and func not in inside]
+
+
+def test_checkpointed_layer_grad_has_no_nk_rows_outside_last_rung():
+    base = get_arch("deepseek-v2-lite-16b").reduced()
+    cfg = dataclasses.replace(base, experts_held=4, moe=dataclasses.replace(
+        base.moe, n_experts=16, top_k=2))
+    b, t, k = 3, 512, 2
+    assert MOE.held_rungs(b * t, k, 4, 16) == (1024, 2048, 3072)
+    p = jax.eval_shape(lambda: TR._block_init(jax.random.PRNGKey(0), cfg,
+                                              jnp.float32))
+    positions = jnp.broadcast_to(jnp.arange(t), (b, t))
+
+    @jax.checkpoint
+    def layer(p, x):
+        y, aux, _ = TR._block_fwd(p, cfg, x, positions)
+        return jnp.sum(y ** 2) + aux
+
+    x = jax.ShapeDtypeStruct((b, t, cfg.d_model), jnp.float32)
+    text = jax.jit(jax.grad(layer, argnums=(0, 1))).lower(p, x).as_text()
+    assert text.count('"stablehlo.case"') >= 2      # forward and backward
+    assert re.search(rf"tensor<{b * t * k}x\d+xf32>", text)  # last rung
+    assert _outside_last_branch(text, b * t * k) == []
 
 
 @pytest.mark.parametrize("norm", [False, True], ids=["raw", "normalised"])

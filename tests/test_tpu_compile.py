@@ -15,6 +15,10 @@ kernel may use, programs that do not fit the device — at no chip time.
   kernel), the SwiGLU's gate, up and down and their backward, at
   DeepSeek-V2-Lite's widths and the deepseek-v2-lite.silo_train cell's
   rows (8 x 2048 tokens x top-6, 8 experts held);
+* the MoE layer's held path (`models/moe.moe_ffn`) under the checkpoint,
+  forward and backward, at the deepseek-v2-lite.silo_train cell's shapes
+  (4 x 2048 tokens, 8 of 64 experts held): each rung's kernel calls, and
+  none whose output goes unused;
 * the training self-attention's splash kernels (`ops.attention`, through
   `layers.flash_attention`), forward and backward, at both cells' shapes:
   granite-8b (batch 1, 32 query heads over 8 KV heads, T 2048, head 128)
@@ -136,6 +140,38 @@ def test_grouped_matmul_swiglu_and_vjp_compile_at_deepseek_widths(
         x, w_in, w_in, w_out, sizes).compile()
     # forward 3, input gradients 3, weight gradients 3
     assert compiled.as_text().count("tpu_custom_call") >= 9
+
+
+def test_held_path_grad_compiles_at_the_deepseek_cell(one_chip,
+                                                      monkeypatch):
+    import dataclasses
+    from repro.configs import get_arch
+    from repro.kernels import ops
+    from repro.models import moe
+    monkeypatch.setattr(ops, "_use_pallas", lambda: True)
+    cfg = dataclasses.replace(get_arch("deepseek-v2-lite-16b"),
+                              experts_held=8)
+    assert moe.held_rungs(4 * 2048, 6, 8, 64) == (7680, 19456, 49152)
+    p = jax.tree.map(lambda a: _spec(a.shape, a.dtype, one_chip),
+                     jax.eval_shape(lambda: moe.moe_init(
+                         jax.random.PRNGKey(0), cfg, jnp.bfloat16)))
+    x = _spec((4, 2048, cfg.d_model), jnp.bfloat16, one_chip)
+
+    @jax.checkpoint
+    def loss(p, x):
+        y, aux, _ = moe.moe_ffn(p, cfg, x)
+        return (y.astype(jnp.float32) ** 2).sum() + aux
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(p, x).compile(
+        ).as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    # the forward's 3 GEMMs at each of the 3 rungs; the backward's input
+    # and weight gradients (6) at each rung, and at the two higher rungs
+    # the forward again (3): no GEMM whose output goes unused
+    assert len(calls) == 3 * 3 + 6 * 3 + 3 * 2
+    assert all(re.search(r'op_name="[^"]*moe\.experts', line)
+               for line in calls)
 
 
 # B, T, query heads, KV heads, qk dim, v dim, scale
